@@ -70,20 +70,9 @@ import argparse
 import json
 import os
 import shutil
-import sys
 import tempfile
 import time
 from typing import Dict, List, Optional
-
-# --mesh-shapes needs virtual CPU devices forced BEFORE the jax backend
-# initialises (which the model imports below trigger); devcount is
-# jax-free and scans argv for the sweep flag
-from repro.distributed import devcount
-
-devcount.force_host_devices_from_argv()
-if "--crash" in sys.argv:
-    # the crash lane's shard-failover leg serves on a 2x1 mesh
-    devcount.force_host_devices(2)
 
 import jax
 import jax.numpy as jnp
@@ -1292,7 +1281,7 @@ def bench_mesh(arch: str, batch: int, n_requests: int, k: int, shapes,
         raise SystemExit(
             f"mesh sweep needs {need} devices but jax sees "
             f"{len(jax.devices())}: pass --mesh-shapes on the command "
-            f"line (the bench forces virtual CPU devices pre-import) or "
+            f"line (the bench forces virtual CPU devices first) or "
             f"set XLA_FLAGS=--xla_force_host_platform_device_count"
             f"={need}")
     header(f"mesh-sharded serving {arch}: shapes "
@@ -1482,6 +1471,14 @@ def main(argv=None):
                     help="CI smoke: tiny workload -> BENCH_*.tiny.json "
                          "(never clobbers the tracked trajectory)")
     args = ap.parse_args(argv)
+    # virtual CPU devices must be forced before the first device query:
+    # the mesh sweep needs its largest shape, the crash lane's
+    # shard-failover leg serves on a 2x1 mesh
+    if args.mesh_shapes:
+        serve_mesh.ensure_host_devices(max(
+            serve_mesh.MeshPlan.parse(s).size for s in args.mesh_shapes))
+    elif args.crash:
+        serve_mesh.ensure_host_devices(2)
     if args.timed:
         n_req = args.n_requests or (24 if args.tiny else 96)
         k = max(args.decode_blocks) if args.decode_blocks else 8

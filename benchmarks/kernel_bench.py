@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from benchmarks.bench_utils import dump_json, header, row, time_call
 from repro.core import blocks as blocks_lib
 from repro.core import scan as scan_lib
+from repro.kernels import resolve_interpret
 from repro.kernels.block_step import ops as block_ops
 from repro.kernels.decode_step import ops as step_ops
 from repro.kernels.decode_step import ref as step_ref
@@ -78,9 +79,9 @@ def main(argv=None) -> dict:
         out[name] = {"us_per_call": us}
         row(f"kernel/{name}", us, "")
 
-    # pallas rows -- real kernels on TPU, interpret-mode timing elsewhere;
+    # pallas rows -- compiled on TPU, interpret-mode timing on the CPU;
     # structural derived either way.
-    interp = scan_ops.DEFAULT_INTERPRET
+    interp = resolve_interpret(None)
     n = a.size
     # linear chunked-scan kernel: read a,b + write h
     us = time_call(

@@ -42,18 +42,13 @@ greedy streams are bit-identical to the uninterrupted run.
 import argparse
 import time
 
-# must precede the jax/model imports: --mesh forces virtual CPU devices,
-# and the device count is pinned the moment the backend initialises
-from repro.distributed import devcount
-
-devcount.force_host_devices_from_argv()
-
 import jax
 import numpy as np
 
 from repro.configs import archs
 from repro.data.lm_corpus import decode_bytes
 from repro.distributed import serve_mesh
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serving.engine import ServingEngine, replay_trace
 from repro.serving.faults import FaultInjector
@@ -155,6 +150,7 @@ def main(argv=None):
                          "shards); forces virtual CPU devices before jax "
                          "initialises")
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.tune_file is None and args.decode_block is None:
         args.decode_block = 4           # the untuned demo default
 

@@ -118,8 +118,8 @@ class ModelConfig:
     param_dtype: str = "float32"
     compute_dtype: str = "float32"
     # minRNN scan execution (core.scan.STRATEGIES): "auto" resolves to the
-    # fused Pallas projection+scan kernels -- real kernels on TPU,
-    # interpret-mode parity elsewhere.  Set "associative" to force the
+    # fused Pallas projection+scan kernels -- compiled on a TPU,
+    # interpreted on the CPU.  Set "associative" to force the
     # pure-jnp reference path.
     scan_strategy: str = "auto"
     # minRNN decode block fusion (kernels/block_step): "auto"/"on" run the

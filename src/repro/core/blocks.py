@@ -98,8 +98,8 @@ class MinRNNBlockConfig:
     mode: str = "log"               # log | linear scan parameterization
     norm: str = "rmsnorm"
     dropout: float = 0.0
-    # core.scan.STRATEGIES; "auto" = fused Pallas kernels (real on TPU,
-    # interpret parity elsewhere).  Callers of ``apply`` may override.
+    # core.scan.STRATEGIES; "auto" = fused Pallas kernels (compiled on a
+    # TPU, interpreted on the CPU).  Callers of ``apply`` may override.
     scan_strategy: str = "auto"
     # whole-block decode fusion (kernels/block_step): "auto"/"on" run
     # norm -> conv -> cell -> down -> MLP as ONE pallas_call per step /
@@ -229,7 +229,7 @@ def step(params, cfg: MinRNNBlockConfig, x_t: Array, state, *,
 
     ``scan_strategy`` defaults to ``cfg.scan_strategy`` (``"auto"`` = the
     fused Pallas decode-step kernel for the cell, ``kernels/decode_step``;
-    real kernel on TPU, interpret parity elsewhere).  Pass e.g.
+    compiled on a TPU, interpreted on the CPU).  Pass e.g.
     ``"sequential"`` to force the pure-jnp cell step (the parity oracle).
     Norm / conv window / down-projection / MLP stay in XLA either way.
 

@@ -140,11 +140,22 @@ def causal_conv_apply(p, x: Array, prefix: Optional[Array] = None) -> Array:
     return y + p["bias"].astype(x.dtype)
 
 
+def conv_taps(taps, k):
+    """sum_i taps[i] * k[i], oldest tap first: the depthwise conv of one
+    position as elementwise multiply-adds.  The decode step and the fused
+    block kernel share it, so both round identically."""
+    y = taps[0] * k[0]
+    for tap, w in zip(taps[1:], k[1:]):
+        y = y + tap * w
+    return y
+
+
 def causal_conv_step(p, x_t: Array, conv_state: Array):
     """Single decode step. conv_state: (..., K-1, D) trailing inputs."""
     k = p["kernel"].astype(x_t.dtype)
     window = jnp.concatenate([conv_state, x_t[..., None, :]], axis=-2)
-    y = jnp.einsum("...kd,kd->...d", window, k) + p["bias"].astype(x_t.dtype)
+    taps = [window[..., i, :] for i in range(window.shape[-2])]
+    y = conv_taps(taps, k) + p["bias"].astype(x_t.dtype)
     return y, window[..., 1:, :]
 
 

@@ -236,9 +236,9 @@ def scan_sequence_parallel(a: Array, b: Array, axis_name: str,
 
 # "fused" = the Pallas fused projection+scan kernels (minGRU/minLSTM layers
 # only; resolved by the cell's ``parallel``, not by ``scan_linear``).
-# "auto" = backend-aware default: the fused Pallas path everywhere -- real
-# TPU kernels on TPU, interpret-mode (bit-compatible semantics, CPU
-# execution) elsewhere, via kernels/*/ops.DEFAULT_INTERPRET.
+# "auto" = the fused Pallas path: compiled on a TPU, run by the Pallas
+# interpreter on the CPU (same semantics), decided when each kernel is
+# called (``repro.kernels.resolve_interpret``).
 STRATEGIES = ("associative", "sequential", "chunked", "pallas", "fused",
               "auto")
 

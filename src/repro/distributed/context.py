@@ -66,20 +66,7 @@ def axis_size(mesh: Optional[Mesh], name: str) -> int:
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: Optional[bool] = None):
-    """Version-portable shard_map.
-
-    Newer jax exposes ``jax.shard_map`` (with ``check_vma``); older releases
-    only have ``jax.experimental.shard_map.shard_map`` (where the same knob
-    is called ``check_rep``).  All internal callers go through here.
-    """
-    kw = {}
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kw["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    if check_vma is not None:
-        kw["check_rep"] = check_vma
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+    """``jax.shard_map``; ``check_vma=None`` keeps JAX's default."""
+    kw = {} if check_vma is None else {"check_vma": check_vma}
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kw)

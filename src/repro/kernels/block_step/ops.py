@@ -33,14 +33,14 @@ the Dh axis -- exact per feature tile, autotuned via
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.block_step import kernel as _kernel
 from repro.kernels.scan.ops import pad_to
-
-DEFAULT_INTERPRET = jax.default_backend() != "tpu"
 
 _SUBLANES = 8     # fp32 sublane multiple; bf16 inputs are upcast in-kernel
 _LANES = 128
@@ -81,6 +81,11 @@ def _tile_plan(dx, dh, dm, block_dh, interpret):
     return dxp, dhp, dmp, bdh
 
 
+def _row(v, n):
+    """A bias / scale vector as the (1, n) row the kernel reads."""
+    return pad_to(v, n, 0)[0][None, :]
+
+
 def _pack(params, x, h, win, valid, *, cell, use_conv, use_mlp, cd,
           block_dh, interpret):
     """Pad everything to the kernel grid and build the flat operand
@@ -93,25 +98,25 @@ def _pack(params, x, h, win, valid, *, cell, use_conv, use_mlp, cd,
     xp, _ = pad_to(x, _SUBLANES, 0)
     bsz = x.shape[0]
     xp, _ = pad_to(xp, dxp, -1)
-    ops = [xp, pad_to(params["norm_rnn"]["scale"], dxp, 0)[0]]
+    ops = [xp, _row(params["norm_rnn"]["scale"], dxp)]
     if use_conv:
+        win = jnp.swapaxes(pad_to(win, _SUBLANES, 0)[0], 0, 1)
         ops += [pad_to(params["conv"]["kernel"], dxp, 1)[0],
-                pad_to(params["conv"]["bias"], dxp, 0)[0],
-                pad_to(pad_to(win, _SUBLANES, 0)[0], dxp, -1)[0]]
+                _row(params["conv"]["bias"], dxp),
+                pad_to(win, dxp, -1)[0]]
     for w, b in _gate_operands(params, cd, x.dtype, cell):
-        ops += [pad_to(pad_to(w, dxp, 0)[0], dhp, 1)[0],
-                pad_to(b, dhp, 0)[0]]
+        ops += [pad_to(pad_to(w, dxp, 0)[0], dhp, 1)[0], _row(b, dhp)]
     ops.append(pad_to(pad_to(h, _SUBLANES, 0)[0], dhp, -1)[0])
     ops.append(pad_to(pad_to(_cast(params["down"]["kernel"], cd),
                              dhp, 0)[0], dxp, 1)[0])
     if use_mlp:
-        ops += [pad_to(params["norm_mlp"]["scale"], dxp, 0)[0],
+        ops += [_row(params["norm_mlp"]["scale"], dxp),
                 pad_to(pad_to(_cast(params["mlp_in"]["kernel"], cd),
                               dxp, 0)[0], dmp, 1)[0],
-                pad_to(_cast(params["mlp_in"]["bias"], cd), dmp, 0)[0],
+                _row(_cast(params["mlp_in"]["bias"], cd), dmp),
                 pad_to(pad_to(_cast(params["mlp_out"]["kernel"], cd),
                               dmp, 0)[0], dxp, 1)[0],
-                pad_to(_cast(params["mlp_out"]["bias"], cd), dxp, 0)[0]]
+                _row(_cast(params["mlp_out"]["bias"], cd), dxp)]
     if valid is not None:
         ops.append(pad_to(valid.astype(jnp.int32)[:, None],
                           _SUBLANES, 0)[0])
@@ -132,11 +137,12 @@ def fused_block_step(params, x_t: jax.Array, state: dict, *,
                      cell: str = "mingru", mode: str = "log",
                      use_conv: bool = False, use_mlp: bool = False,
                      compute_dtype=None, block_dh: int = 0,
-                     interpret: bool = DEFAULT_INTERPRET):
+                     interpret: Optional[bool] = None):
     """One whole-block decode step in one Pallas call.  x_t: (..., D),
     state: {"h": (..., Dh)[, "conv": (..., K-1, D)]} -> (y, new_state),
     bit-identical to ``blocks.step`` on the cell-fused path (single
     feature tile)."""
+    interpret = resolve_interpret(interpret)
     win = state.get("conv") if use_conv else None
     arrs = [x_t, state["h"]] + ([win] if use_conv else [])
     trails = [1, 1] + ([2] if use_conv else [])
@@ -154,7 +160,7 @@ def fused_block_step(params, x_t: jax.Array, state: dict, *,
     new_state = dict(state)
     new_state["h"] = h
     if use_conv:
-        new_state["conv"] = outs[2][:bsz, :, :dx]
+        new_state["conv"] = jnp.swapaxes(outs[2], 0, 1)[:bsz, :, :dx]
     if lead is not None:
         y = y.reshape(lead + y.shape[1:])
         new_state = {k: v.reshape(lead + v.shape[1:])
@@ -167,12 +173,13 @@ def fused_block_chunk(params, x: jax.Array, state: dict,
                       mode: str = "log", use_conv: bool = False,
                       use_mlp: bool = False, compute_dtype=None,
                       block_dh: int = 0, return_positions: bool = False,
-                      interpret: bool = DEFAULT_INTERPRET):
+                      interpret: Optional[bool] = None):
     """Varlen C-token whole-block chunk in one Pallas call (the packed
     prefill / speculative-verify form).  x: (B, C, D), valid: (B,) int32
     in [1, C] -> (ys, new_state[, per-position states]), matching
     ``blocks.step_chunk`` with ``return_positions``."""
     chunk = x.shape[1]
+    interpret = resolve_interpret(interpret)
     win = state.get("conv") if use_conv else None
 
     # weight/state operands from a (B, D) probe, then swap in the padded
@@ -194,7 +201,7 @@ def fused_block_chunk(params, x: jax.Array, state: dict,
     new_state["h"] = hs[:, -1]          # frozen rows: == hs[:, valid-1]
     pos_states = {"h": hs}
     if use_conv:
-        wins = jnp.swapaxes(outs[2], 0, 1)[:bsz, :chunk, :, :dx]
+        wins = jnp.moveaxis(outs[2], 2, 0)[:bsz, :chunk, :, :dx]
         new_state["conv"] = wins[:, -1]
         pos_states["conv"] = wins
     if return_positions:

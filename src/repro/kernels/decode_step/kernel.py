@@ -94,16 +94,16 @@ def mingru_step_kernel(x: jax.Array, wz: jax.Array, bz: jax.Array,
         in_specs=[
             pl.BlockSpec((bsz, dx), lambda j: (0, 0)),
             pl.BlockSpec((dx, block_dh), lambda j: (0, j)),
-            pl.BlockSpec((block_dh,), lambda j: (j,)),
+            pl.BlockSpec((1, block_dh), lambda j: (0, j)),
             pl.BlockSpec((dx, block_dh), lambda j: (0, j)),
-            pl.BlockSpec((block_dh,), lambda j: (j,)),
+            pl.BlockSpec((1, block_dh), lambda j: (0, j)),
             pl.BlockSpec((bsz, block_dh), lambda j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bsz, block_dh), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, dh), x.dtype),
         interpret=interpret,
         **kwargs,
-    )(x, wz, bz, wh, bh, h_prev)
+    )(x, wz, bz.reshape(1, dh), wh, bh.reshape(1, dh), h_prev)
 
 
 def _mingru_chunk_kernel(x_ref, wz_ref, bz_ref, wh_ref, bh_ref, h_ref,
@@ -172,9 +172,9 @@ def mingru_chunk_kernel(x: jax.Array, wz: jax.Array, bz: jax.Array,
         in_specs=[
             pl.BlockSpec((chunk, bsz, dx), lambda j: (0, 0, 0)),
             pl.BlockSpec((dx, block_dh), lambda j: (0, j)),
-            pl.BlockSpec((block_dh,), lambda j: (j,)),
+            pl.BlockSpec((1, block_dh), lambda j: (0, j)),
             pl.BlockSpec((dx, block_dh), lambda j: (0, j)),
-            pl.BlockSpec((block_dh,), lambda j: (j,)),
+            pl.BlockSpec((1, block_dh), lambda j: (0, j)),
             pl.BlockSpec((bsz, block_dh), lambda j: (0, j)),
             pl.BlockSpec((bsz, 1), lambda j: (0, 0)),
         ],
@@ -182,7 +182,7 @@ def mingru_chunk_kernel(x: jax.Array, wz: jax.Array, bz: jax.Array,
         out_shape=jax.ShapeDtypeStruct((chunk, bsz, dh), x.dtype),
         interpret=interpret,
         **kwargs,
-    )(x, wz, bz, wh, bh, h_prev, valid)
+    )(x, wz, bz.reshape(1, dh), wh, bh.reshape(1, dh), h_prev, valid)
 
 
 def _minlstm_step_kernel(x_ref, wf_ref, bf_ref, wi_ref, bi_ref, wh_ref,
@@ -236,18 +236,19 @@ def minlstm_step_kernel(x: jax.Array, wf: jax.Array, bf: jax.Array,
         in_specs=[
             pl.BlockSpec((bsz, dx), lambda j: (0, 0)),
             pl.BlockSpec((dx, block_dh), lambda j: (0, j)),
-            pl.BlockSpec((block_dh,), lambda j: (j,)),
+            pl.BlockSpec((1, block_dh), lambda j: (0, j)),
             pl.BlockSpec((dx, block_dh), lambda j: (0, j)),
-            pl.BlockSpec((block_dh,), lambda j: (j,)),
+            pl.BlockSpec((1, block_dh), lambda j: (0, j)),
             pl.BlockSpec((dx, block_dh), lambda j: (0, j)),
-            pl.BlockSpec((block_dh,), lambda j: (j,)),
+            pl.BlockSpec((1, block_dh), lambda j: (0, j)),
             pl.BlockSpec((bsz, block_dh), lambda j: (0, j)),
         ],
         out_specs=pl.BlockSpec((bsz, block_dh), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((bsz, dh), x.dtype),
         interpret=interpret,
         **kwargs,
-    )(x, wf, bf, wi, bi, wh, bh, h_prev)
+    )(x, wf, bf.reshape(1, dh), wi, bi.reshape(1, dh), wh,
+      bh.reshape(1, dh), h_prev)
 
 
 def _minlstm_chunk_kernel(x_ref, wf_ref, bf_ref, wi_ref, bi_ref, wh_ref,
@@ -310,11 +311,11 @@ def minlstm_chunk_kernel(x: jax.Array, wf: jax.Array, bf: jax.Array,
         in_specs=[
             pl.BlockSpec((chunk, bsz, dx), lambda j: (0, 0, 0)),
             pl.BlockSpec((dx, block_dh), lambda j: (0, j)),
-            pl.BlockSpec((block_dh,), lambda j: (j,)),
+            pl.BlockSpec((1, block_dh), lambda j: (0, j)),
             pl.BlockSpec((dx, block_dh), lambda j: (0, j)),
-            pl.BlockSpec((block_dh,), lambda j: (j,)),
+            pl.BlockSpec((1, block_dh), lambda j: (0, j)),
             pl.BlockSpec((dx, block_dh), lambda j: (0, j)),
-            pl.BlockSpec((block_dh,), lambda j: (j,)),
+            pl.BlockSpec((1, block_dh), lambda j: (0, j)),
             pl.BlockSpec((bsz, block_dh), lambda j: (0, j)),
             pl.BlockSpec((bsz, 1), lambda j: (0, 0)),
         ],
@@ -322,4 +323,5 @@ def minlstm_chunk_kernel(x: jax.Array, wf: jax.Array, bf: jax.Array,
         out_shape=jax.ShapeDtypeStruct((chunk, bsz, dh), x.dtype),
         interpret=interpret,
         **kwargs,
-    )(x, wf, bf, wi, bi, wh, bh, h_prev, valid)
+    )(x, wf, bf.reshape(1, dh), wi, bi.reshape(1, dh), wh,
+      bh.reshape(1, dh), h_prev, valid)

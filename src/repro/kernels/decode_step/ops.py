@@ -12,8 +12,8 @@ their ``scan_strategy`` resolves to ``"fused"`` (the config default
 ``"auto"``), which is how ``blocks.step`` -> ``lm.decode_step`` ->
 ``lm.superstep`` put the whole serving hot path on Pallas: the engine's
 unified device loop drives prefilling (teacher-forced prompt tokens) and
-decoding rows through this same kernel in the same round -- real kernels
-on TPU, interpret-mode parity elsewhere.
+decoding rows through this same kernel in the same round -- compiled on
+a TPU, interpreted on the CPU (``repro.kernels.resolve_interpret``).
 
 The ``*_chunk`` wrappers serve double duty: packed prefill
 (``lm.decode_chunk``) and speculative-decode verification
@@ -29,10 +29,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.decode_step import kernel as _kernel
 from repro.kernels.scan.ops import call_with_flat_lead, pad_to
-
-DEFAULT_INTERPRET = jax.default_backend() != "tpu"
 
 _SUBLANES = 8     # fp32 sublane multiple; bf16 inputs are upcast in-kernel
 _LANES = 128
@@ -63,10 +62,11 @@ def fused_mingru_step(x: jax.Array, wz: jax.Array, bz: Optional[jax.Array],
                       wh: jax.Array, bh: Optional[jax.Array],
                       h_prev: jax.Array, *, mode: str = "log",
                       block_dh: int = 128,
-                      interpret: bool = DEFAULT_INTERPRET) -> jax.Array:
+                      interpret: Optional[bool] = None) -> jax.Array:
     """minGRU cell step (projections + gates + state update), one Pallas
     call.  x: (..., Dx), h_prev: (..., Dh) -> h_t: (..., Dh)."""
     dh = wz.shape[1]
+    interpret = resolve_interpret(interpret)
     block_dh = _tile(dh, block_dh, interpret)
     if bz is None:
         bz = jnp.zeros((dh,), x.dtype)
@@ -94,10 +94,11 @@ def fused_minlstm_step(x: jax.Array, wf: jax.Array, bf: Optional[jax.Array],
                        wh: jax.Array, bh: Optional[jax.Array],
                        h_prev: jax.Array, *, mode: str = "log",
                        normalize: bool = True, block_dh: int = 128,
-                       interpret: bool = DEFAULT_INTERPRET) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """minLSTM cell step (three projections + stable f/(f+i) normalisation
     + state update), one Pallas call.  Shapes as fused_mingru_step."""
     dh = wf.shape[1]
+    interpret = resolve_interpret(interpret)
     block_dh = _tile(dh, block_dh, interpret)
     if bf is None:
         bf = jnp.zeros((dh,), x.dtype)
@@ -141,7 +142,7 @@ def fused_mingru_chunk(x: jax.Array, wz: jax.Array, bz: Optional[jax.Array],
                        wh: jax.Array, bh: Optional[jax.Array],
                        h_prev: jax.Array, valid: jax.Array, *,
                        mode: str = "log", block_dh: int = 128,
-                       interpret: bool = DEFAULT_INTERPRET) -> jax.Array:
+                       interpret: Optional[bool] = None) -> jax.Array:
     """Packed varlen minGRU chunk in one Pallas call: weights stream from
     HBM once for up to C prompt tokens.  x: (..., C, Dx), h_prev:
     (..., Dh), valid: (...,) int32 in [1, C] -> hs: (..., C, Dh); row b
@@ -149,6 +150,7 @@ def fused_mingru_chunk(x: jax.Array, wz: jax.Array, bz: Optional[jax.Array],
     state.  Bit-identical to ``valid[b]`` sequential ``fused_mingru_step``
     calls (the packed superstep's C=1 parity contract rides on this)."""
     dh = wz.shape[1]
+    interpret = resolve_interpret(interpret)
     block_dh = _tile(dh, block_dh, interpret)
     if bz is None:
         bz = jnp.zeros((dh,), x.dtype)
@@ -177,10 +179,11 @@ def fused_minlstm_chunk(x: jax.Array, wf: jax.Array, bf: Optional[jax.Array],
                         h_prev: jax.Array, valid: jax.Array, *,
                         mode: str = "log", normalize: bool = True,
                         block_dh: int = 128,
-                        interpret: bool = DEFAULT_INTERPRET) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """Packed varlen minLSTM chunk; contract as :func:`fused_mingru_chunk`
     (bit-identical to sequential ``fused_minlstm_step`` calls)."""
     dh = wf.shape[1]
+    interpret = resolve_interpret(interpret)
     block_dh = _tile(dh, block_dh, interpret)
     if bf is None:
         bf = jnp.zeros((dh,), x.dtype)

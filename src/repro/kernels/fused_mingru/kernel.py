@@ -9,11 +9,17 @@ scan on the VPU, and writes only h.  Per-block HBM traffic drops from
 reading x + writing k,v + reading k,v + writing h  to  reading x + weights
 + writing h.
 
-VMEM budget per block (fp32): bt*Dx + 2*Dx*bdh + 3*bt*bdh floats.
-With bt=256, Dx<=2048, bdh=128: 2048*256*4 + 2*2048*128*4 + ... ~ 4.5 MB --
-fits v5e's 16 MB higher-level VMEM comfortably.  The weight blocks are
-re-fetched per time chunk; index_map pins them so Mosaic hoists the copy
-out of the sequential grid dimension (revisiting the same block is free).
+VMEM per grid step, at the paper LMs' published widths (Dx = 768, bf16,
+bt = 256, bdh = 128): the x tile 256 x 768 x 2 B = 384 KiB and two
+768 x 128 weight tiles of 192 KiB, each double-buffered, plus their fp32
+copies and a few (bt, bdh) fp32 gate tiles -- about 3 MiB, inside
+Mosaic's default 16 MiB scope (v5e has 128 MiB of VMEM).  Forward and
+forward+backward compile for a v5e at B = 8, T = 2048
+(``tests/test_tpu_compile.py``).  The weight blocks' index_map ignores
+the sequential time axis, so Mosaic fetches them once per feature tile.
+Vectors ride as (1, n) rows and h0 as (B, 1, Dh): the TPU tiling needs
+the last two block dims to be multiples of (8, 128) or the full array
+dims.
 """
 
 from __future__ import annotations
@@ -35,7 +41,7 @@ def _fused_kernel(x_ref, wz_ref, bz_ref, wh_ref, bh_ref, h0_ref,
 
     @pl.when(k_idx == 0)
     def _init():
-        carry_ref[...] = h0_ref[...].astype(carry_ref.dtype)
+        carry_ref[...] = h0_ref[0].astype(carry_ref.dtype)
 
     x = x_ref[0].astype(jnp.float32)                      # (bt, Dx)
     wz = wz_ref[...].astype(jnp.float32)                  # (Dx, bdh)
@@ -78,10 +84,10 @@ def fused_mingru_kernel(x: jax.Array, wz: jax.Array, bz: jax.Array,
         in_specs=[
             pl.BlockSpec((1, block_t, dx), lambda i, j, k: (i, k, 0)),
             pl.BlockSpec((dx, block_dh), lambda i, j, k: (0, j)),
-            pl.BlockSpec((block_dh,), lambda i, j, k: (j,)),
+            pl.BlockSpec((1, block_dh), lambda i, j, k: (0, j)),
             pl.BlockSpec((dx, block_dh), lambda i, j, k: (0, j)),
-            pl.BlockSpec((block_dh,), lambda i, j, k: (j,)),
-            pl.BlockSpec((1, block_dh), lambda i, j, k: (i, j)),
+            pl.BlockSpec((1, block_dh), lambda i, j, k: (0, j)),
+            pl.BlockSpec((1, 1, block_dh), lambda i, j, k: (i, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, block_t, block_dh),
                                lambda i, j, k: (i, k, j)),
@@ -89,4 +95,4 @@ def fused_mingru_kernel(x: jax.Array, wz: jax.Array, bz: jax.Array,
         scratch_shapes=[pltpu.VMEM((1, block_dh), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(x, wz, bz, wh, bh, h0)
+    )(x, wz, bz.reshape(1, dh), wh, bh.reshape(1, dh), h0[:, None, :])

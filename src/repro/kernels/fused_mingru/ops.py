@@ -12,8 +12,8 @@ chunked-scan kernel reversed,
 
 followed by the transposed projection matmuls (dWz/dWh/dx/db*), which XLA
 derives from the rematerialised gate computation -- so forward AND backward
-of the default training hot path run through Pallas (interpret mode
-off-TPU).  The gate pre-activations are recomputed from x in the backward
+of the default training hot path run through Pallas (compiled on a TPU,
+interpreted on the CPU).  The gate pre-activations are recomputed from x in the backward
 (two matmuls, standard rematerialisation) rather than saved, keeping the
 forward's HBM win.
 """
@@ -27,14 +27,14 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import nn
+from repro.kernels import resolve_interpret
 from repro.kernels.fused_mingru import kernel as _kernel
 from repro.kernels.scan import ops as scan_ops
-
-DEFAULT_INTERPRET = jax.default_backend() != "tpu"
 
 
 def _run(x, wz, bz, wh, bh, h0, mode, block_t, block_dh, interpret):
     """Pad T to the time tile and Dh to the feature tile, run, slice."""
+    interpret = resolve_interpret(interpret)
     t, dh = x.shape[1], wz.shape[1]
     bt = scan_ops.round_block_t(block_t, t)
     x, _ = scan_ops.pad_to(x, bt, 1)
@@ -91,7 +91,7 @@ def fused_mingru(x: jax.Array, wz: jax.Array, bz: Optional[jax.Array],
                  wh: jax.Array, bh: Optional[jax.Array],
                  h0: Optional[jax.Array] = None, *, mode: str = "log",
                  block_t: int = 256, block_dh: int = 128,
-                 interpret: bool = DEFAULT_INTERPRET) -> jax.Array:
+                 interpret: Optional[bool] = None) -> jax.Array:
     """minGRU layer forward (projections + recurrence) in one Pallas call.
 
     Differentiable in x, wz, bz, wh, bh and h0 (carried state, so chunked

@@ -20,15 +20,15 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import min_lstm, nn
+from repro.kernels import resolve_interpret
 from repro.kernels.fused_minlstm import kernel as _kernel
 from repro.kernels.scan import ops as scan_ops
-
-DEFAULT_INTERPRET = jax.default_backend() != "tpu"
 
 
 def _run(x, wf, bf, wi, bi, wh, bh, h0, mode, normalize, block_t, block_dh,
          interpret):
     """Pad T to the time tile and Dh to the feature tile, run, slice."""
+    interpret = resolve_interpret(interpret)
     t, dh = x.shape[1], wf.shape[1]
     bt = scan_ops.round_block_t(block_t, t)
     x, _ = scan_ops.pad_to(x, bt, 1)
@@ -97,7 +97,7 @@ def fused_minlstm(x: jax.Array, wf: jax.Array, bf: Optional[jax.Array],
                   h0: Optional[jax.Array] = None, *, mode: str = "log",
                   normalize: bool = True, block_t: int = 256,
                   block_dh: int = 128,
-                  interpret: bool = DEFAULT_INTERPRET) -> jax.Array:
+                  interpret: Optional[bool] = None) -> jax.Array:
     """minLSTM layer forward (projections + recurrence) in one Pallas call.
 
     Differentiable in x, the three weight/bias pairs and h0.

@@ -79,7 +79,7 @@ def _scan_kernel(a_ref, b_ref, h0_ref, o_ref, carry_ref):
 
     @pl.when(k == 0)
     def _init():
-        carry_ref[...] = h0_ref[...].astype(carry_ref.dtype)
+        carry_ref[...] = h0_ref[0].astype(carry_ref.dtype)
 
     a = a_ref[0].astype(jnp.float32)          # (bt, bd)
     b = b_ref[0].astype(jnp.float32)
@@ -115,7 +115,7 @@ def linear_scan_kernel(a: jax.Array, b: jax.Array, h0: jax.Array,
         in_specs=[
             pl.BlockSpec((1, block_t, block_d), lambda i, j, k: (i, k, j)),
             pl.BlockSpec((1, block_t, block_d), lambda i, j, k: (i, k, j)),
-            pl.BlockSpec((1, block_d), lambda i, j, k: (i, j)),
+            pl.BlockSpec((1, 1, block_d), lambda i, j, k: (i, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, block_t, block_d),
                                lambda i, j, k: (i, k, j)),
@@ -123,7 +123,7 @@ def linear_scan_kernel(a: jax.Array, b: jax.Array, h0: jax.Array,
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(a, b, h0)
+    )(a, b, h0[:, None, :])
 
 
 def _log_scan_kernel(la_ref, lb_ref, lh0_ref, o_ref, carry_ref):
@@ -138,7 +138,7 @@ def _log_scan_kernel(la_ref, lb_ref, lh0_ref, o_ref, carry_ref):
 
     @pl.when(k == 0)
     def _init():
-        carry_ref[...] = lh0_ref[...].astype(carry_ref.dtype)
+        carry_ref[...] = lh0_ref[0].astype(carry_ref.dtype)
 
     la = la_ref[0].astype(jnp.float32)        # (bt, bd) cumulative log a
     lb = lb_ref[0].astype(jnp.float32)
@@ -175,7 +175,7 @@ def log_scan_kernel(log_a: jax.Array, log_b: jax.Array, log_h0: jax.Array,
         in_specs=[
             pl.BlockSpec((1, block_t, block_d), lambda i, j, k: (i, k, j)),
             pl.BlockSpec((1, block_t, block_d), lambda i, j, k: (i, k, j)),
-            pl.BlockSpec((1, block_d), lambda i, j, k: (i, j)),
+            pl.BlockSpec((1, 1, block_d), lambda i, j, k: (i, 0, j)),
         ],
         out_specs=pl.BlockSpec((1, block_t, block_d),
                                lambda i, j, k: (i, k, j)),
@@ -183,4 +183,4 @@ def log_scan_kernel(log_a: jax.Array, log_b: jax.Array, log_h0: jax.Array,
         scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(log_a, log_b, log_h0)
+    )(log_a, log_b, log_h0[:, None, :])

@@ -23,7 +23,7 @@ coefficients a_{t+1} live in (0, 1) and its values dL/dh_t are finite and
 signed, so it is numerically safe in linear space: the *forward* kernel
 needs log space (long products of gates underflow), the backward reuses
 the linear kernel reversed.  Both directions of both entry points run the
-Pallas chunked-scan kernels (interpret mode off-TPU).
+Pallas chunked-scan kernels (compiled on a TPU, interpreted on the CPU).
 """
 
 from __future__ import annotations
@@ -35,9 +35,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.scan import kernel as _kernel
-
-DEFAULT_INTERPRET = jax.default_backend() != "tpu"
 
 
 def call_with_flat_lead(fn, *specs):
@@ -85,6 +84,7 @@ _pad_to = pad_to   # internal alias
 
 def _run(a, b, h0, block_t, block_d, interpret):
     """Pad to tile multiples, run kernel, slice back."""
+    interpret = resolve_interpret(interpret)
     t, d = a.shape[-2], a.shape[-1]
     bt = round_block_t(block_t, t)
     a_p, _ = _pad_to(a, bt, -2, 1.0)       # identity coefficient
@@ -123,7 +123,7 @@ def reverse_scan_grads(a, dh, h, h0, block_t, block_d, interpret):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def linear_scan(a: jax.Array, b: jax.Array, h0: jax.Array,
                 block_t: int = 256, block_d: int = 128,
-                interpret: bool = DEFAULT_INTERPRET) -> jax.Array:
+                interpret: Optional[bool] = None) -> jax.Array:
     """Differentiable h_t = a_t h_{t-1} + b_t, Pallas-accelerated.
 
     a, b: (B, T, D); h0: (B, D).  Arbitrary T/D (padded to tiles).
@@ -162,6 +162,7 @@ def linear_scan_auto(a: jax.Array, b: jax.Array,
 
 def _run_log(log_a, log_b, log_h0, block_t, block_d, interpret):
     """Pad to tile multiples with the log identity (0, -inf), run, slice."""
+    interpret = resolve_interpret(interpret)
     t, d = log_a.shape[-2], log_a.shape[-1]
     bt = round_block_t(block_t, t)
     la_p, _ = _pad_to(log_a, bt, -2, 0.0)         # log a = 0  <=>  a = 1
@@ -177,7 +178,7 @@ def _run_log(log_a, log_b, log_h0, block_t, block_d, interpret):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def log_space_scan(log_a: jax.Array, log_b: jax.Array, log_h0: jax.Array,
                    block_t: int = 256, block_d: int = 128,
-                   interpret: bool = DEFAULT_INTERPRET) -> jax.Array:
+                   interpret: Optional[bool] = None) -> jax.Array:
     """Differentiable Heinsen-style scan, Pallas-accelerated.
 
     h_t = exp(log_a_t) h_{t-1} + exp(log_b_t);  log_a, log_b: (B, T, D);

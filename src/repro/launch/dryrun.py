@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (assignment: MULTI-POD DRY-RUN step 3).
 
 Lowers + compiles every (architecture x input-shape x mesh) cell against
@@ -12,7 +9,16 @@ terms to JSONL.
   python -m repro.launch.dryrun --all --out results/dryrun.jsonl
 
 --all orchestrates one subprocess per cell (isolation + resumability).
+
+The dry-run lowers on 512 virtual CPU devices, so this module pins JAX to
+the CPU before JAX is imported; the orchestrating parent and its children
+never hold an accelerator.
 """
+
+import os
+
+os.environ["JAX_PLATFORMS"] = "cpu"
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 import argparse
 import dataclasses
@@ -145,8 +151,6 @@ def _cell_costs(cfg, shape, mesh, microbatches: int = 1) -> dict:
     with mesh_ctx.use_mesh(mesh, pure_dp=bool(getattr(cfg, "pure_dp", 0))):
         compiled = jax.jit(fn, **kw).lower(*args).compile()
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):    # older jax: one dict per program
-        ca = ca[0] if ca else {}
     coll = hlo_analysis.collective_stats(compiled.as_text())
     return {
         "flops": float(ca.get("flops", 0.0)),

@@ -7,17 +7,24 @@ touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _mesh(shape, axes):
+    """A mesh whose axes are Auto: the model code places activations with
+    ``with_sharding_constraint``, which Explicit axes refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 2, model: int = 2, pod: int = 0):
     """Small mesh for subprocess SPMD tests (XLA host device count)."""
     if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return _mesh((pod, data, model), ("pod", "data", "model"))
+    return _mesh((data, model), ("data", "model"))

@@ -30,18 +30,12 @@ from __future__ import annotations
 import argparse
 import time
 
-# --mesh needs the virtual-device flag exported BEFORE the model stack
-# imports below touch jax (kernel modules initialise the backend);
-# jax-free by construction, safe as the very first repro import
-from repro.distributed import devcount
-
-devcount.force_host_devices_from_argv()
-
 import jax
 
 from repro.configs import archs
 from repro.data.lm_corpus import decode_bytes
 from repro.distributed import serve_mesh
+from repro.launch import compile_cache
 from repro.models import lm
 from repro.serving.engine import ServingEngine
 from repro.training import checkpoint as ckpt_lib
@@ -118,11 +112,8 @@ def main(argv=None):
                     help="serving mesh shape, e.g. 4x1 (data-parallel "
                          "slot shards) or 2x2 (+ tensor-parallel gate "
                          "projections).  On CPU the launcher forces DxM "
-                         "virtual devices -- this must happen before jax "
-                         "initialises, so pass --mesh rather than "
-                         "constructing the engine yourself, or set "
-                         "XLA_FLAGS=--xla_force_host_platform_device_"
-                         "count=N in the environment")
+                         "virtual devices before jax initialises its "
+                         "backend")
     ap.add_argument("--snapshot-dir", default=None, metavar="DIR",
                     help="arm crash recovery: journal every submit/"
                          "cancel/step to DIR/journal.jsonl and snapshot "
@@ -141,6 +132,7 @@ def main(argv=None):
                          "--prompts on top.  Keeps journaling into DIR")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    compile_cache.enable()
     if args.tune_file == "none":
         args.tune_file = None
 

@@ -12,7 +12,8 @@ watchdog).  ``--smoke`` swaps in the reduced config for CPU runs;
 from __future__ import annotations
 
 import argparse
-import functools
+import contextlib
+import tempfile
 import time
 
 import jax
@@ -21,6 +22,7 @@ import numpy as np
 
 from repro.configs import archs
 from repro.data import lm_corpus, synthetic
+from repro.launch import compile_cache
 from repro.training import checkpoint as ckpt_lib
 from repro.training import optimizer as opt_lib
 from repro.training import train_step as ts_lib
@@ -52,12 +54,24 @@ def main(argv=None):
     ap.add_argument("--seq", type=int, default=256)
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--microbatches", type=int, default=1)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint directory; the run resumes from the "
+                         "newest checkpoint found there.  Default: a fresh "
+                         "temporary directory, removed at exit")
     ap.add_argument("--ckpt-every", type=int, default=25)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--simulate-failure", type=int, default=-1)
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args(argv)
+    compile_cache.enable()
+    with contextlib.ExitStack() as stack:
+        if args.ckpt_dir is None:
+            args.ckpt_dir = stack.enter_context(
+                tempfile.TemporaryDirectory(prefix="repro_ckpt_"))
+        return _run(args)
+
+
+def _run(args):
 
     cfg = archs.smoke(args.arch) if args.smoke else archs.get(args.arch)
     if args.task == "lm" and cfg.vocab_size != 256:

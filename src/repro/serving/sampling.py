@@ -37,10 +37,11 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 Array = jax.Array
 
-_NEG = jnp.float32(-1e30)     # "removed from support" without -inf NaN risk
+_NEG = np.float32(-1e30)     # "removed from support" without -inf NaN risk
 
 
 def validate_controls(temperature: float, top_k: int, top_p: float) -> None:

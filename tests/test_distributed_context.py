@@ -48,10 +48,9 @@ def test_shard_map_wrapper_check_vma_kw():
 
 
 def test_shard_map_wrapper_new_jax_branch(monkeypatch):
-    """With jax.shard_map present the wrapper must prefer it and pass
-    check_vma through under that name (not check_rep)."""
+    """The wrapper calls jax.shard_map and passes check_vma through."""
     _need_devices(2)
-    from jax.experimental.shard_map import shard_map as real
+    real = jax.shard_map
     seen = {}
 
     def fake_shard_map(f, *, mesh, in_specs, out_specs, **kw):
@@ -67,32 +66,6 @@ def test_shard_map_wrapper_new_jax_branch(monkeypatch):
     out = jax.jit(fn)(jnp.zeros(4))
     np.testing.assert_array_equal(np.asarray(out), np.ones(4))
     assert seen == {"check_vma": False}
-
-
-def test_shard_map_wrapper_old_jax_fallback(monkeypatch):
-    """Without jax.shard_map the wrapper must route through
-    jax.experimental.shard_map with check_vma renamed to check_rep."""
-    _need_devices(2)
-    import jax.experimental.shard_map as esm
-    real = esm.shard_map
-    seen = {}
-
-    def fake_shard_map(f, *, mesh, in_specs, out_specs, **kw):
-        seen.update(kw)
-        kw.pop("check_rep", None)
-        return real(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
-
-    if hasattr(jax, "shard_map"):
-        monkeypatch.delattr(jax, "shard_map")
-    monkeypatch.setattr(esm, "shard_map", fake_shard_map)
-
-    mesh = jax.make_mesh((2,), ("data",))
-    fn = mesh_ctx.shard_map(lambda v: v + 1.0, mesh=mesh,
-                            in_specs=P("data"), out_specs=P("data"),
-                            check_vma=False)
-    out = jax.jit(fn)(jnp.zeros(4))
-    np.testing.assert_array_equal(np.asarray(out), np.ones(4))
-    assert seen == {"check_rep": False}
 
 
 # ---------------------------------------------------------------------------

@@ -307,8 +307,7 @@ def test_log_vs_linear_bf16_drift_at_4096():
 # ---------------------------------------------------------------------------
 
 def _x64():
-    from jax.experimental import enable_x64
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def test_linear_scan_gradcheck_vs_sequential_fp64():
@@ -522,3 +521,37 @@ def test_lm_default_dispatch_hits_fused_kernel(monkeypatch):
     grads = jax.grad(lambda p: lm.loss_fn(p, cfg, batch)[0])(params)
     assert calls["n"] > 0
     assert all(bool(jnp.all(jnp.isfinite(g))) for g in jax.tree.leaves(grads))
+
+
+# ---------------------------------------------------------------------------
+# interpret mode is decided per call, never at import
+# ---------------------------------------------------------------------------
+
+def test_resolve_interpret_follows_the_backend(monkeypatch):
+    from repro import kernels
+    assert kernels.resolve_interpret(True) is True
+    assert kernels.resolve_interpret(False) is False
+    for backend, want in (("cpu", True), ("tpu", False)):
+        monkeypatch.setattr(jax, "default_backend", lambda b=backend: b)
+        assert kernels.resolve_interpret(None) is want
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="TPU"):
+        kernels.resolve_interpret(None)
+
+
+def test_model_and_engine_import_initialise_no_backend():
+    """Importing the model stack must leave the device choice open: a
+    launcher can still force virtual devices, and no kernel module fixes
+    interpret mode before a backend exists."""
+    import os
+    import subprocess
+    import sys
+    code = ("from jax._src import xla_bridge as xb\n"
+            "import repro.models.lm, repro.serving.engine\n"
+            "import repro.launch.serve, repro.launch.train\n"
+            "assert not xb.backends_are_initialized()\n")
+    env = dict(os.environ, PYTHONPATH=os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "src"))
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=300)
+    assert proc.returncode == 0, proc.stderr[-2000:]
