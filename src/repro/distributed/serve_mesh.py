@@ -250,7 +250,8 @@ def serve_params_shardings(params, cfg, plan: MeshPlan, mesh: Mesh):
 # ---------------------------------------------------------------------------
 
 _PLAIN_COUNTERS = ("prefill_steps", "prefill_rounds", "wasted_slot_steps",
-                   "nonfinite_decode_rounds")
+                   "nonfinite_decode_rounds", "packed_rounds",
+                   "packed_tokens")
 _SPEC_COUNTERS = _PLAIN_COUNTERS + ("draft_proposed", "draft_accepted",
                                     "emit_rounds")
 

@@ -38,6 +38,7 @@ from repro.distributed import serve_mesh
 from repro.launch import compile_cache
 from repro.models import lm
 from repro.serving.engine import ServingEngine
+from repro.serving.scheduler import PHASES
 from repro.training import checkpoint as ckpt_lib
 
 
@@ -220,6 +221,11 @@ def main(argv=None):
           f"{snap['ttft_rounds_mean']:.1f} device rounds), "
           f"inter-token {snap['itl_s_mean'] * 1e3:.1f}ms "
           f"({snap['itl_rounds_mean']:.2f} rounds/token)")
+    calls = max(snap["decode_calls"], 1)
+    print("host phases (ms per superstep call): " + ", ".join(
+        f"{k} {snap[k + '_time_s'] * 1e3 / calls:.3f}" for k in PHASES)
+          + f"; packed rounds {snap['packed_rounds']}, "
+          f"{snap['packed_tokens']} positions filled")
     if args.speculative:
         print(f"speculative ({args.speculative}, S={args.draft_len}): "
               f"{snap['draft_accepted']}/{snap['draft_proposed']} drafts "

@@ -743,10 +743,15 @@ def superstep(params, cfg, state: Dict[str, Any], n: int, *,
     requests within a single call), the advanced slot state, and
     ``counters`` with ``prefill_steps`` (prompt tokens consumed -- up to
     C per slot-round when packing), ``prefill_rounds`` (slot-rounds
-    spent prefilling; equals ``prefill_steps`` at C=1) and
+    spent prefilling; equals ``prefill_steps`` at C=1),
     ``wasted_slot_steps`` (rows stepped while dead with nothing staged
     -- the idle waste this loop exists to eliminate; rows keep stepping
-    regardless so the batch stays dense and shapes stay static).
+    regardless so the batch stays dense and shapes stay static), and
+    ``packed_rounds`` / ``packed_tokens``: the rounds that took the
+    packed branch (some row prefilling, C > 1) and the positions in them
+    that carried a real token -- each prefilling row's prompt tokens
+    plus one per live decoding row, of ``B * C`` computed; both 0 at
+    C=1.
 
     **Numerical health guard**: every round, each row's fresh logits
     (and recurrent state, for recurrent-cache archs) are reduced to a
@@ -785,7 +790,9 @@ def superstep(params, cfg, state: Dict[str, Any], n: int, *,
     ``draft_accepted`` (sum of drafts offered / accepted on decoding
     rows) and ``emit_rounds`` (emitting slot-rounds == tokens the non-
     speculative path contributes: ``decode_tokens == draft_accepted +
-    emit_rounds`` exactly).  Requires ``supports_prompt_packing(cfg)``.
+    emit_rounds`` exactly); in ``packed_tokens`` a decoding row counts
+    its 1 + drafts verified positions.  Requires
+    ``supports_prompt_packing(cfg)``.
     """
     from repro.serving import sampling
 
@@ -807,7 +814,7 @@ def superstep(params, cfg, state: Dict[str, Any], n: int, *,
     chunk = int(prompt_chunk)
 
     def body(carry, _):
-        st, prefill_ct, round_ct, waste_ct, nf_ct = carry
+        st, prefill_ct, round_ct, waste_ct, nf_ct, pk_rounds, pk_toks = carry
         st = dict(st)
 
         # 1. re-admission from the staging buffer
@@ -846,6 +853,10 @@ def superstep(params, cfg, state: Dict[str, Any], n: int, *,
                              jnp.minimum(left, chunk), 0).astype(jnp.int32)
             prefill_ct = prefill_ct + jnp.sum(take)
             valid = jnp.maximum(take, 1)        # non-prefilling rows: 1
+            packed = jnp.any(prefilling)
+            pk_rounds = pk_rounds + packed.astype(jnp.int32)
+            pk_toks = pk_toks + jnp.where(packed, jnp.sum(jnp.where(
+                alive, valid, 0)), 0)
 
             # 3. packed varlen block step, all rows in one batch -- but
             # only when some row is actually prefilling: steady-state
@@ -901,17 +912,19 @@ def superstep(params, cfg, state: Dict[str, Any], n: int, *,
         st["alive"] = alive & jnp.logical_not(died | bad)
         st["tok"] = jnp.where(emitting, toks, st["tok"])
         st["prompt_pos"] = pos_next
-        return (st, prefill_ct, round_ct, waste_ct, nf_ct), \
-            (emit, emit_rid, bad)
+        return (st, prefill_ct, round_ct, waste_ct, nf_ct, pk_rounds,
+                pk_toks), (emit, emit_rid, bad)
 
     zero = jnp.zeros((), jnp.int32)
-    (state, prefill_ct, round_ct, waste_ct, nf_ct), \
+    (state, prefill_ct, round_ct, waste_ct, nf_ct, pk_rounds, pk_toks), \
         (emitted, rids, nonfinite) = lax.scan(
-            body, (state, zero, zero, zero, zero), None, length=n)
+            body, (state,) + (zero,) * 6, None, length=n)
     counters = {"prefill_steps": prefill_ct,
                 "prefill_rounds": round_ct,
                 "wasted_slot_steps": waste_ct,
                 "nonfinite_decode_rounds": nf_ct,
+                "packed_rounds": pk_rounds,
+                "packed_tokens": pk_toks,
                 "nonfinite": jnp.swapaxes(nonfinite, 0, 1)}
     return (jnp.swapaxes(emitted, 0, 1), jnp.swapaxes(rids, 0, 1),
             state, counters)
@@ -1010,6 +1023,11 @@ def _superstep_spec(params, cfg, state: Dict[str, Any], n: int, *,
         tok_blk = jnp.where(prefilling[:, None], gathered, dec_blk)
         valid_in = jnp.where(prefilling, jnp.maximum(take, 1),
                              1 + n_draft).astype(i32)
+        if chunk > 1:
+            packed = jnp.any(prefilling)
+            ct["packed_rounds"] += packed.astype(i32)
+            ct["packed_tokens"] += jnp.where(
+                packed, jnp.sum(jnp.where(alive, valid_in, 0)), 0)
         logits_all, pstates = decode_verify(params, cfg, tok_blk,
                                             valid_in, st["cache"])
 
@@ -1119,7 +1137,7 @@ def _superstep_spec(params, cfg, state: Dict[str, Any], n: int, *,
     counters0 = {k: zero for k in (
         "prefill_steps", "prefill_rounds", "wasted_slot_steps",
         "draft_proposed", "draft_accepted", "emit_rounds",
-        "nonfinite_decode_rounds")}
+        "nonfinite_decode_rounds", "packed_rounds", "packed_tokens")}
     (state, counters), (emitted, rids, nonfinite) = lax.scan(
         body, (state, counters0), None, length=n)
     counters["nonfinite"] = jnp.swapaxes(nonfinite, 0, 1)

@@ -152,6 +152,8 @@ class Request:
     first_token_s: float = 0.0
     first_round: int = 0
     admit_seq: int = -1           # staging order (FIFO fairness witness)
+    staged_s: float = 0.0         # parked in a staging buffer (_stage)
+    armed_s: float = 0.0          # seen armed by the drain (_promote)
 
 
 class EngineStallError(RuntimeError):
@@ -569,6 +571,7 @@ class ServingEngine:
                 load[i // self._rows_per_shard] += \
                     self._service_rounds(parked)
         m = self._smirror
+        now_s = time.perf_counter()
         for req in group:
             empty.sort(key=lambda i: (load[i // self._rows_per_shard],
                                       self._row_eta(i), i))
@@ -576,6 +579,7 @@ class ServingEngine:
             load[slot // self._rows_per_shard] += self._service_rounds(req)
             req.slot = slot
             req.status = STAGED
+            req.staged_s = now_s
             req.admit_seq = self.stats.admitted
             self.staged[slot] = req
             m["s_prompt"][slot, :] = 0
@@ -611,9 +615,10 @@ class ServingEngine:
         masked False for this call (the device must never arm a row
         whose prompt row it does not have), and the slot stays dirty so
         the next call retries -- the request arms one superstep late.
+        Returns the number of prompt rows scattered.
         """
         if not self._dirty_slots:
-            return
+            return 0
         rows = sorted(set(self._dirty_slots))
         dropped: List[int] = []
         if self.faults is not None:
@@ -633,6 +638,7 @@ class ServingEngine:
             src = s_valid if k == "s_valid" else self._smirror[k]
             self.state[k] = jnp.asarray(src)
         self._dirty_slots = list(dropped)
+        return len(rows)
 
     # ------------------------------------------------------------------
     # The superstep
@@ -654,8 +660,11 @@ class ServingEngine:
             self._superstep_fns[key] = fn
         return fn
 
-    def _promote(self, slot: int) -> Request:
-        """The device armed this row's staged request: update mirrors."""
+    def _promote(self, slot: int, now: float) -> Request:
+        """The device armed this row's staged request: update mirrors.
+        ``now`` is the drain's clock, so ``armed_s`` resolves to one
+        engine call; the ``engine.arm`` event carries the request's wait
+        in the queue and parked in staging."""
         prev = self.current[slot]
         assert prev is None or prev.done, \
             "device armed a row whose request the host still thinks is live"
@@ -664,6 +673,10 @@ class ServingEngine:
         self.current[slot] = req
         self.staged[slot] = None
         req.status = RUNNING
+        req.armed_s = now
+        self.stats.mark("arm", rid=req.rid,
+                        queued_us=1e6 * (req.staged_s - req.submitted_s),
+                        parked_us=1e6 * (now - req.staged_s))
         return req
 
     def _retire(self, req: Request, status: str):
@@ -805,7 +818,8 @@ class ServingEngine:
                                  backoff=False):
                     self.stats.failover_requeued += 1
 
-    def _quarantine(self, slot: int, round_: int, s_valid_np, dirty):
+    def _quarantine(self, slot: int, round_: int, s_valid_np, dirty,
+                    now: float):
         """The superstep's health guard killed this row at ``round_``:
         attribute the kill to the occupying request and re-enqueue it
         under the bounded retry budget (exponential round backoff), or
@@ -820,7 +834,7 @@ class ServingEngine:
             # nothing before the kill, so the drain never promoted it)
             if self.staged[slot] is not None and not s_valid_np[slot] \
                     and slot not in dirty:
-                req = self._promote(slot)
+                req = self._promote(slot, now)
             else:
                 return
         self.current[slot] = None
@@ -868,45 +882,87 @@ class ServingEngine:
         from staging in-loop.  Drains emissions, quarantines rows the
         numerical health guard killed, and restocks staging.  Returns
         the number of requests still in flight (armed + staged +
-        queued)."""
+        queued).
+
+        Each host phase is timed into ``stats`` and recorded as an
+        ``engine.*`` profiler span (``EngineStats.timed``), nested as
+        ``step`` > ``sweep``, ``stage``, ``upload``, ``decode`` >
+        (``dispatch``, ``fetch``), ``drain`` > ``fetch``, ``journal``."""
         k = max(1, int(n_tokens)) if n_tokens is not None \
             else self.decode_block
-        self._sweep_deadlines()
-        if self.faults is not None:
-            for s in self.faults.shard_crash(self.stats.decode_steps, k,
-                                             self.dp):
-                if s not in self.dead_shards:
-                    self._crash_shard(s, self.stats.decode_steps)
-        self._stage()
+        with self.stats.timed("step"):
+            return self._step(k)
+
+    def _step(self, k: int) -> int:
+        with self.stats.timed("sweep"):
+            self._sweep_deadlines()
+            if self.faults is not None:
+                for s in self.faults.shard_crash(self.stats.decode_steps, k,
+                                                 self.dp):
+                    if s not in self.dead_shards:
+                        self._crash_shard(s, self.stats.decode_steps)
+        with self.stats.timed("stage"):
+            self._stage()
         if not any(self.current) and not any(self.staged):
             if self.journal is not None:
-                # every step() call is journaled, no-ops included: the
-                # replay must re-execute the exact call sequence
-                self.journal.record_step({
-                    "round": self.stats.decode_steps, "k": k,
-                    "noop": True})
-                self._maybe_snapshot()
+                with self.stats.timed("journal"):
+                    # every step() call is journaled, no-ops included:
+                    # the replay must re-execute the exact call sequence
+                    self.journal.record_step({
+                        "round": self.stats.decode_steps, "k": k,
+                        "noop": True})
+                    self._maybe_snapshot()
             return len(self.scheduler)
-        self._upload_staging()
+        with self.stats.timed("upload") as span:
+            span.set_metadata(rows=self._upload_staging())
         if self.faults is not None:
-            slots = self.faults.corrupt_state(
-                self.stats.decode_steps, k, self.max_batch)
-            if slots:
-                self._corrupt_slots(slots)
+            with self.stats.timed("sweep"):
+                slots = self.faults.corrupt_state(
+                    self.stats.decode_steps, k, self.max_batch)
+                if slots:
+                    self._corrupt_slots(slots)
 
         with self.stats.timed("decode"):
-            toks, rids, self.state, counters = self._superstep_fn(k)(
-                self.params, self.draft_params, self.state)
-            toks_np = np.asarray(toks)
-            rids_np = np.asarray(rids)
-            s_valid_np = np.asarray(self.state["s_valid"])
-            nf_np = np.asarray(counters["nonfinite"])
-            self._prompt_pos[:] = np.asarray(self.state["prompt_pos"])
-            self._rid_dev[:] = np.asarray(self.state["rid"])
+            with self.stats.timed("dispatch"):
+                toks, rids, self.state, counters = self._superstep_fn(k)(
+                    self.params, self.draft_params, self.state)
+            with self.stats.timed("fetch"):
+                toks_np = np.asarray(toks)
+                rids_np = np.asarray(rids)
+                s_valid_np = np.asarray(self.state["s_valid"])
+                nf_np = np.asarray(counters["nonfinite"])
+                self._prompt_pos[:] = np.asarray(self.state["prompt_pos"])
+                self._rid_dev[:] = np.asarray(self.state["rid"])
             if self.faults is not None:
                 stall = self.faults.straggler(self.stats.decode_calls)
                 if stall > 0:
                     time.sleep(stall)
+        with self.stats.timed("drain") as span:
+            base_round, emits = self._drain(k, toks_np, rids_np, s_valid_np,
+                                            nf_np, counters, span)
+        if self.journal is not None:
+            with self.stats.timed("journal"):
+                # the step record lands AFTER the superstep drains:
+                # crashing mid-step replays the whole step (the journal
+                # never saw it)
+                self.journal.record_step({
+                    "round": base_round, "k": k, "emits": emits,
+                    "digest": self._journal_digest()})
+                self._maybe_snapshot()
+        return (sum(r is not None for r in self.current)
+                + sum(r is not None for r in self.staged)
+                + len(self.scheduler))
+
+    def _drain(self, k, toks_np, rids_np, s_valid_np, nf_np, counters,
+               span):
+        """Host side of one superstep: fold its counters into ``stats``,
+        hand each emitted token to its request, promote and retire, and
+        re-sync the staging mirror.  Returns the call's first device
+        round and its ``[rid, token]`` emissions in drain order.  The
+        call's counts go onto the ``engine.drain`` ``span``, with the
+        rows one packed round computes (``slots``: a data shard's, as
+        each shard takes the packed branch on its own) and its width
+        (``chunk``)."""
         if toks_np.ndim == 2:       # non-speculative: one plane per round
             toks_np = toks_np[:, :, None]
             rids_np = rids_np[:, :, None]
@@ -917,9 +973,13 @@ class ServingEngine:
         # under a mesh the counters come back as (data,) per-shard
         # vectors (single device: scalars -- atleast_1d unifies both);
         # the global stats take the cross-shard sum, the per-shard
-        # ShardStats take their own component
-        percall = {kk: np.atleast_1d(np.asarray(v))
-                   for kk, v in counters.items() if kk != "nonfinite"}
+        # ShardStats take their own component.  They come over in one
+        # transfer: read one by one, each is a device-to-host round trip
+        # of its own (~0.25 ms on a TPU v5e host)
+        with self.stats.timed("fetch"):
+            percall = {kk: np.atleast_1d(v) for kk, v in jax.device_get(
+                {kk: v for kk, v in counters.items()
+                 if kk != "nonfinite"}).items()}
         agg = {kk: int(v.sum()) for kk, v in percall.items()}
         self.stats.prefill_tokens += agg["prefill_steps"]
         self.stats.prefill_rounds += agg["prefill_rounds"]
@@ -927,6 +987,8 @@ class ServingEngine:
         self.stats.nonfinite_decode_rounds += agg["nonfinite_decode_rounds"]
         self.stats.draft_proposed += agg.get("draft_proposed", 0)
         self.stats.draft_accepted += agg.get("draft_accepted", 0)
+        self.stats.packed_rounds += agg.get("packed_rounds", 0)
+        self.stats.packed_tokens += agg.get("packed_tokens", 0)
         for s, sh in enumerate(self.stats.shards):
             sh.slot_steps += k * self._rows_per_shard
             sh.prefill_rounds += int(percall["prefill_rounds"][s])
@@ -945,14 +1007,14 @@ class ServingEngine:
             for j in range(k):
                 if nf_np[slot, j]:
                     self._quarantine(slot, base_round + j, s_valid_np,
-                                     dirty)
+                                     dirty, now)
                 for c in range(toks_np.shape[2]):
                     rid = int(rids_np[slot, j, c])
                     if rid < 0:
                         continue
                     req = self.current[slot]
                     if req is None or req.rid != rid:
-                        req = self._promote(slot)   # armed mid-superstep
+                        req = self._promote(slot, now)   # armed mid-superstep
                         assert req.rid == rid, (req.rid, rid)
                     t = int(toks_np[slot, j, c])
                     if not req.out:
@@ -973,7 +1035,7 @@ class ServingEngine:
             # a slot whose upload was dropped is still parked, not armed
             if self.staged[slot] is not None and not s_valid_np[slot] \
                     and slot not in dirty:
-                self._promote(slot)
+                self._promote(slot, now)
         self.stats.decode_tokens += drained
         # non_spec_tokens: tokens the non-speculative path contributes --
         # one per emitting slot-round.  The device counts those rounds
@@ -992,16 +1054,13 @@ class ServingEngine:
         for slot in dirty:
             if self.staged[slot] is not None:
                 self._smirror["s_valid"][slot] = True
-        if self.journal is not None:
-            # the step record lands AFTER the superstep drains: crashing
-            # mid-step replays the whole step (the journal never saw it)
-            self.journal.record_step({"round": base_round, "k": k,
-                                      "emits": emits,
-                                      "digest": self._journal_digest()})
-            self._maybe_snapshot()
-        return (sum(r is not None for r in self.current)
-                + sum(r is not None for r in self.staged)
-                + len(self.scheduler))
+        span.set_metadata(rounds=k, emitted=drained,
+                          prefill_tokens=agg["prefill_steps"],
+                          packed_rounds=agg.get("packed_rounds", 0),
+                          packed_tokens=agg.get("packed_tokens", 0),
+                          slots=self._rows_per_shard,
+                          chunk=self.prompt_chunk)
+        return base_round, emits
 
     def _journal_digest(self) -> Dict[str, int]:
         """Round-clock stats fingerprint written with every step record;

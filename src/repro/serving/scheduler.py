@@ -40,6 +40,8 @@ import math
 import time
 from typing import Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 # ---------------------------------------------------------------------------
 # Admission verdicts (returned by AdmissionScheduler.submit)
 # ---------------------------------------------------------------------------
@@ -203,6 +205,36 @@ class ShardStats:
             + self.wasted_slot_steps + self.nonfinite_decode_rounds)
 
 
+# the host phases of ``ServingEngine.step`` that ``EngineStats.timed``
+# times, outermost first: each adds to ``<phase>_time_s`` and records the
+# profiler span ``engine.<phase>``
+PHASES = ("step", "sweep", "stage", "upload", "decode", "dispatch", "fetch",
+          "drain", "journal")
+SPAN_PREFIX = "engine."
+
+
+class _Phase:
+    """One timed phase: the perf_counter interval added to its
+    ``<kind>_time_s`` lies inside the profiler span of the same name."""
+    __slots__ = ("stats", "field", "ann", "t0")
+
+    def __init__(self, stats, kind: str):
+        self.stats = stats
+        self.field = f"{kind}_time_s"
+        self.ann = TraceAnnotation(SPAN_PREFIX + kind)
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self.ann
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
+        setattr(self.stats, self.field, getattr(self.stats, self.field) + dt)
+        return False
+
+
 def _percentile(xs: List[float], q: float) -> float:
     if not xs:
         return 0.0
@@ -230,8 +262,23 @@ class EngineStats:
     wasted_slot_steps + nonfinite_decode_rounds`` (a request's first
     token rides its final prefill round; a round whose emission the
     non-finite guard suppressed is counted by the last term -- see
-    below).  Timers wrap the device calls including host sync, so
-    tokens-per-second is an end-to-end number.
+    below).  ``packed_rounds`` counts the device rounds that took the
+    packed branch of the superstep (some row prefilling, ``prompt_chunk``
+    > 1) and ``packed_tokens`` the positions in them that carried a real
+    token (each prefilling row's prompt tokens, one per live decoding
+    row); both stay 0 at C=1.
+
+    Phase timers: ``timed(kind)`` adds a phase's wall time to
+    ``<kind>_time_s`` for each of :data:`PHASES` -- ``step`` (the whole
+    ``ServingEngine.step`` call), inside it ``sweep`` (deadline sweep and
+    fault hooks), ``stage``, ``upload`` (staging rows to the device),
+    ``decode`` (the superstep call and its blocking reads: ``dispatch``
+    and ``fetch``), ``drain`` (counter reads -- a second ``fetch`` --,
+    the slot loop and the staging mirror's re-sync) and ``journal``
+    (crash-recovery runs only).  Each phase is also the profiler span
+    ``engine.<kind>`` on the device trace's clock.  ``tokens_per_second``
+    divides by ``decode_time_s`` alone: it is the superstep's rate, and
+    leaves out every other host phase, so it is not an end-to-end number.
 
     Per-request latency: ``ttft_s`` / ``ttft_rounds`` measure submit ->
     first token (wall clock at host drain granularity, and exact device
@@ -305,7 +352,18 @@ class EngineStats:
     # DP-shard failover (serving/recovery.py + faults.shard_crash)
     shard_crashes: int = 0
     failover_requeued: int = 0
+    packed_rounds: int = 0
+    packed_tokens: int = 0
+    # host phase timers (``timed``), seconds summed over calls
+    step_time_s: float = 0.0
+    sweep_time_s: float = 0.0
+    stage_time_s: float = 0.0
+    upload_time_s: float = 0.0
     decode_time_s: float = 0.0
+    dispatch_time_s: float = 0.0
+    fetch_time_s: float = 0.0
+    drain_time_s: float = 0.0
+    journal_time_s: float = 0.0
     ttft_s: List[float] = dataclasses.field(default_factory=list)
     ttft_rounds: List[int] = dataclasses.field(default_factory=list)
     itl_s: List[float] = dataclasses.field(default_factory=list)
@@ -341,20 +399,19 @@ class EngineStats:
             self.itl_s.append((last_s - first_s) / (n_tokens - 1))
 
     def timed(self, kind: str):
-        """Context manager: adds elapsed wall time to ``<kind>_time_s``."""
-        stats = self
+        """Context manager around one host phase: adds its elapsed wall
+        time to ``<kind>_time_s`` and records it as the profiler span
+        ``engine.<kind>``.  ``__enter__`` returns the span, whose
+        ``set_metadata(**stats)`` gives it stats.  Without a running
+        profiler the span costs about a microsecond."""
+        return _Phase(self, kind)
 
-        class _Timer:
-            def __enter__(self):
-                self.t0 = time.perf_counter()
-
-            def __exit__(self, *exc):
-                dt = time.perf_counter() - self.t0
-                setattr(stats, f"{kind}_time_s",
-                        getattr(stats, f"{kind}_time_s") + dt)
-                return False
-
-        return _Timer()
+    @staticmethod
+    def mark(kind: str, **span_stats) -> None:
+        """A zero-length profiler event ``engine.<kind>``: a point in a
+        request's life, carried by its stats."""
+        with TraceAnnotation(SPAN_PREFIX + kind, **span_stats):
+            pass
 
     @property
     def total_tokens(self) -> int:

@@ -208,6 +208,35 @@ def test_shard_stats_identity_and_aggregation(setup):
     assert len(snap["shards"]) == 4
 
 
+@pytest.mark.parametrize("mesh,one_at_a_time", [("1x2", False),
+                                                 ("2x1", True)])
+def test_packed_counters_match_single_device(setup, mesh, one_at_a_time):
+    """``packed_rounds`` / ``packed_tokens`` ride the mesh superstep's
+    per-shard counters.  Each data shard takes the packed branch on its
+    own rows, so the sums equal the single-device engine's whenever one
+    data shard packs at a time: always with one data shard (TP alone),
+    and under DP with one request in flight."""
+    cfg, params = setup
+    _need_devices(serve_mesh.MeshPlan.parse(mesh).size)
+
+    def run(m):
+        eng = ServingEngine(cfg, params, max_batch=4, max_len=96,
+                            decode_block=4, prompt_chunk=4, mesh=m)
+        rng = np.random.RandomState(7)
+        for _ in range(5):
+            eng.submit(rng.randint(1, cfg.vocab_size,
+                                   size=rng.randint(3, 12)).tolist(),
+                       max_new=6)
+            if one_at_a_time:
+                eng.run_to_completion()
+        eng.run_to_completion()
+        return eng.stats
+
+    ref, got = run(None), run(mesh)
+    assert got.packed_rounds == ref.packed_rounds > 0
+    assert got.packed_tokens == ref.packed_tokens > 0
+
+
 def test_wasted_slot_steps_land_on_the_idle_shard(setup):
     """One long request pins shard 0 while shard 1 sits empty: the idle
     shard accrues the wasted slot-steps, the busy one the work."""
