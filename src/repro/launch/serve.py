@@ -225,7 +225,9 @@ def main(argv=None):
     print("host phases (ms per superstep call): " + ", ".join(
         f"{k} {snap[k + '_time_s'] * 1e3 / calls:.3f}" for k in PHASES)
           + f"; packed rounds {snap['packed_rounds']}, "
-          f"{snap['packed_tokens']} positions filled")
+          f"{snap['packed_tokens']} positions filled; drained in one go "
+          f"{snap['drain_bulk_slots']} of "
+          f"{snap['decode_calls'] * engine.max_batch} slots")
     if args.speculative:
         print(f"speculative ({args.speculative}, S={args.draft_len}): "
               f"{snap['draft_accepted']}/{snap['draft_proposed']} drafts "

@@ -957,12 +957,17 @@ class ServingEngine:
                span):
         """Host side of one superstep: fold its counters into ``stats``,
         hand each emitted token to its request, promote and retire, and
-        re-sync the staging mirror.  Returns the call's first device
-        round and its ``[rid, token]`` emissions in drain order.  The
-        call's counts go onto the ``engine.drain`` ``span``, with the
-        rows one packed round computes (``slots``: a data shard's, as
-        each shard takes the packed branch on its own) and its width
-        (``chunk``)."""
+        re-sync the staging mirror.  A slot whose planes are plain
+        decode (``_bulk_ok``) has its tokens appended in one go; every
+        other slot is walked round by round, in the same slot order, so
+        streams, events and counters do not depend on which way a slot
+        went.  Returns the call's first device round and, with a journal
+        attached, its ``[rid, token]`` emissions in drain order (else
+        None).  The call's counts go onto the ``engine.drain`` ``span``,
+        with the rows one packed round computes (``slots``: a data
+        shard's, as each shard takes the packed branch on its own), its
+        width (``chunk``) and the pool's rows drained in one go
+        (``bulk_slots``, of ``slots`` x data shards)."""
         if toks_np.ndim == 2:       # non-speculative: one plane per round
             toks_np = toks_np[:, :, None]
             rids_np = rids_np[:, :, None]
@@ -999,43 +1004,68 @@ class ServingEngine:
 
         now = time.perf_counter()
         dirty = set(self._dirty_slots)
-        drained = 0
-        drained_shard = [0] * self.dp
-        emits: List[List[int]] = []     # (rid, token) in drain order
-        for slot in range(self.max_batch):
-            shard = slot // self._rows_per_shard
-            for j in range(k):
-                if nf_np[slot, j]:
-                    self._quarantine(slot, base_round + j, s_valid_np,
-                                     dirty, now)
-                for c in range(toks_np.shape[2]):
-                    rid = int(rids_np[slot, j, c])
-                    if rid < 0:
-                        continue
-                    req = self.current[slot]
-                    if req is None or req.rid != rid:
-                        req = self._promote(slot, now)   # armed mid-superstep
-                        assert req.rid == rid, (req.rid, rid)
-                    t = int(toks_np[slot, j, c])
-                    if not req.out:
-                        req.first_token_s = now
-                        req.first_round = base_round + j
-                        self.stats.record_first_token(
-                            now - req.submitted_s,
-                            base_round + j + 1 - req.submit_round)
-                        self.stats.shards[shard].first_tokens += 1
-                    req.out.append(t)
-                    emits.append([rid, t])
-                    drained += 1
-                    drained_shard[shard] += 1
-                    if (req.eos is not None and t == req.eos) or \
-                            len(req.out) >= req.max_new:
-                        self._finish(req, now, base_round + j)
+        # each slot's planes in (round, plane) order; an entry with rid
+        # >= 0 is an emitted token, and every one of them is drained
+        b = self.max_batch
+        toks_flat = toks_np.reshape(b, -1)
+        rids_flat = rids_np.reshape(b, -1)
+        emitted = rids_flat >= 0
+        drained_shard = emitted.sum(1).reshape(self.dp, -1).sum(1).tolist()
+        drained = sum(drained_shard)
+        every = emitted.all(1).tolist()
+        toks_rows = toks_flat.tolist()
+        rids_rows = rids_flat.tolist()
+        rid_lo = np.where(emitted, rids_flat,
+                          np.iinfo(rids_flat.dtype).max).min(1).tolist()
+        rid_hi = rids_flat.max(1).tolist()
+        nf_any = nf_np.any(1).tolist()
+        emits = [] if self.journal is not None else None  # [rid, token]
+        bulk = 0
+        for slot in range(b):
+            req = self.current[slot]
+            toks = toks_rows[slot] if every[slot] else [
+                t for t, r in zip(toks_rows[slot], rids_rows[slot]) if r >= 0]
+            if self._bulk_ok(req, toks, rid_lo[slot], rid_hi[slot],
+                             nf_any[slot]):
+                bulk += 1
+                if toks:
+                    req.out.extend(toks)
+                    if emits is not None:
+                        emits.extend([req.rid, t] for t in toks)
+            else:
+                shard = slot // self._rows_per_shard
+                for j in range(k):
+                    if nf_np[slot, j]:
+                        self._quarantine(slot, base_round + j, s_valid_np,
+                                         dirty, now)
+                    for c in range(toks_np.shape[2]):
+                        rid = int(rids_np[slot, j, c])
+                        if rid < 0:
+                            continue
+                        req = self.current[slot]
+                        if req is None or req.rid != rid:
+                            req = self._promote(slot, now)  # armed mid-call
+                            assert req.rid == rid, (req.rid, rid)
+                        t = int(toks_np[slot, j, c])
+                        if not req.out:
+                            req.first_token_s = now
+                            req.first_round = base_round + j
+                            self.stats.record_first_token(
+                                now - req.submitted_s,
+                                base_round + j + 1 - req.submit_round)
+                            self.stats.shards[shard].first_tokens += 1
+                        req.out.append(t)
+                        if emits is not None:
+                            emits.append([rid, t])
+                        if (req.eos is not None and t == req.eos) or \
+                                len(req.out) >= req.max_new:
+                            self._finish(req, now, base_round + j)
             # armed without emitting yet (still prefilling at call end);
             # a slot whose upload was dropped is still parked, not armed
             if self.staged[slot] is not None and not s_valid_np[slot] \
                     and slot not in dirty:
                 self._promote(slot, now)
+        self.stats.drain_bulk_slots += bulk
         self.stats.decode_tokens += drained
         # non_spec_tokens: tokens the non-speculative path contributes --
         # one per emitting slot-round.  The device counts those rounds
@@ -1059,8 +1089,28 @@ class ServingEngine:
                           packed_rounds=agg.get("packed_rounds", 0),
                           packed_tokens=agg.get("packed_tokens", 0),
                           slots=self._rows_per_shard,
-                          chunk=self.prompt_chunk)
+                          chunk=self.prompt_chunk, bulk_slots=bulk)
         return base_round, emits
+
+    @staticmethod
+    def _bulk_ok(req: Optional[Request], toks: List[int], rid_lo: int,
+                 rid_hi: int, nonfinite: bool) -> bool:
+        """Whether a slot's planes from one superstep are plain decode,
+        so the drain can append its emitted tokens ``toks`` in one go:
+        no round went non-finite, and either nothing was emitted (an
+        idle or prefilling row) or every token came from the request
+        already running in the row (``rid_lo == rid_hi == req.rid``),
+        past its first token, with no stop token and short of
+        ``max_new``.  Any other slot -- a promote, a first token, a
+        finish, a quarantine -- takes the per-round walk."""
+        if nonfinite:
+            return False
+        if not toks:
+            return True
+        return (req is not None and not req.done and bool(req.out)
+                and rid_lo == rid_hi == req.rid
+                and (req.eos is None or req.eos not in toks)
+                and len(req.out) + len(toks) < req.max_new)
 
     def _journal_digest(self) -> Dict[str, int]:
         """Round-clock stats fingerprint written with every step record;

@@ -266,7 +266,10 @@ class EngineStats:
     packed branch of the superstep (some row prefilling, ``prompt_chunk``
     > 1) and ``packed_tokens`` the positions in them that carried a real
     token (each prefilling row's prompt tokens, one per live decoding
-    row); both stay 0 at C=1.
+    row); both stay 0 at C=1.  ``drain_bulk_slots`` counts the slot
+    rows the drain handled in one go (plain decode, or nothing emitted)
+    rather than round by round; over ``decode_calls`` x rows it is the
+    share of the pool the drain's fast case covered.
 
     Phase timers: ``timed(kind)`` adds a phase's wall time to
     ``<kind>_time_s`` for each of :data:`PHASES` -- ``step`` (the whole
@@ -354,6 +357,7 @@ class EngineStats:
     failover_requeued: int = 0
     packed_rounds: int = 0
     packed_tokens: int = 0
+    drain_bulk_slots: int = 0
     # host phase timers (``timed``), seconds summed over calls
     step_time_s: float = 0.0
     sweep_time_s: float = 0.0

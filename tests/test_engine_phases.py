@@ -31,7 +31,8 @@ PARENT = {"engine.sweep": "engine.step", "engine.stage": "engine.step",
 DRAIN_STATS = {"rounds": "decode_steps", "emitted": "decode_tokens",
                "prefill_tokens": "prefill_tokens",
                "packed_rounds": "packed_rounds",
-               "packed_tokens": "packed_tokens"}
+               "packed_tokens": "packed_tokens",
+               "bulk_slots": "drain_bulk_slots"}
 
 
 @pytest.fixture(scope="module")
@@ -136,6 +137,18 @@ def test_drain_stats_equal_the_counter_deltas(traced):
     arms = [e[3] for e in events if e[0] == "engine.arm"]
     assert sorted(a["rid"] for a in arms) == sorted(rids)
     assert all(a["queued_us"] >= 0 and a["parked_us"] >= 0 for a in arms)
+
+
+def test_drain_span_counts_the_bulk_slots(traced):
+    """Each ``engine.drain`` span says how many of the pool's rows the
+    drain handled in one go; over the calls they add up to the
+    ``EngineStats`` total, and plain decode rows do take the fast case."""
+    events, calls, _ = traced
+    drains = [e[3] for e in events if e[0] == "engine.drain"]
+    assert all(0 <= d["bulk_slots"] <= d["slots"] for d in drains), drains
+    total = sum(d["bulk_slots"] for d in drains)
+    assert total == sum(delta["drain_bulk_slots"] for delta, _ in calls)
+    assert 0 < total < sum(d["slots"] for d in drains)
 
 
 def test_phase_times_fit_inside_the_call(traced):
