@@ -51,7 +51,7 @@ def serving(cell, seed, seconds):
     gc.collect()
     pick = [seqs[i] for i in serve.sample(
         seqs, cell.traffic["check"]["sample_requests"], seed)]
-    gaps = serve.logit_gaps(cell.conf, seed, pick,
+    gaps = serve.logit_gaps(cell.model, cell.conf, seed, pick,
                             cell.settings["engine"]["max_len"],
                             control=True)
     return {"seed": seed, "requests": len(pick), **gaps}
@@ -88,14 +88,14 @@ def training(cell, seed, faults: bool):
         sess.params = sess.opt_state = sess.step_fn = None
         gc.collect()
         if ref is None:
-            ref = train.follow_reference(cell.conf, seed, s["batch"],
-                                         cell.traffic["seq_len"], opt,
-                                         s["reference_rows"])
+            ref = train.follow_reference(cell.model, cell.conf, seed,
+                                         s["batch"], cell.traffic["seq_len"],
+                                         opt, s["reference_rows"])
         out[label] = {k: v["value"] for k, v in
                       train.compare(readings, ref, ALL).items()}
     if not faults:
         return out
-    ctrl = train.follow_reference(cell.conf, seed, s["batch"],
+    ctrl = train.follow_reference(cell.model, cell.conf, seed, s["batch"],
                                   cell.traffic["seq_len"], opt,
                                   s["reference_rows"], control=True)
     out["control"] = {k: v["value"] for k, v in

@@ -22,7 +22,8 @@ the program's own entry points, from one thread.  After each call it
 reads the tokens of the requests in a slot and of those finished since
 the call before, and never walks the queued backlog.  Once it has closed, a
 seeded sample of the greedy requests finished in the window, the longest
-among them, is run through the float32 reference.  For each served
+among them, is run through the float32 reference, the ``forward`` of the
+configuration's model module (``bench/models/``).  For each served
 token it reads the gap by which the token's reference logit lies below
 the reference's best logit at that position; the widest gap over the
 sample is compared with the cell's limit.  A lower precision shows in
@@ -43,7 +44,6 @@ import numpy as np
 
 import corpus
 import harness
-import reference
 import weights
 
 FAILED = ("FAILED", "SHED", "TIMED_OUT")
@@ -116,8 +116,8 @@ class Session:
         from repro.serving.engine import ServingEngine
 
         conf, e = self.cell.conf, self.engine_cfg
-        cfg = harness.program_config(conf)
-        params = weights.make(conf, self.run.seed)
+        cfg = harness.program_config(conf, self.cell.model)
+        params = weights.make(self.cell.model, conf, self.run.seed)
         want = jax.eval_shape(lambda k: lm.init_params(k, cfg),
                               jax.random.PRNGKey(0))
         if jax.tree.structure(want) != jax.tree.structure(params) or any(
@@ -322,8 +322,8 @@ class Session:
             return {"max_logit_gap": {"value": math.inf, "limit": limit}}
         pick = [seqs[i] for i in sample(
             seqs, self.traffic["check"]["sample_requests"], self.run.seed)]
-        gaps = logit_gaps(self.cell.conf, self.run.seed, pick,
-                          self.engine_cfg["max_len"])
+        gaps = logit_gaps(self.cell.model, self.cell.conf, self.run.seed,
+                          pick, self.engine_cfg["max_len"])
         harness.log(f"compared {gaps['tokens']} served tokens of "
                     f"{len(pick)} requests with the reference")
         return {"max_logit_gap": {"value": gaps["program_max"],
@@ -348,20 +348,7 @@ def sample(seqs: list, n: int, seed: int) -> list:
     return pick
 
 
-_FWD = {}
-
-
-def _forward(cell: str, vocab: int):
-    import jax
-    if (cell, vocab) not in _FWD:
-        _FWD[cell, vocab] = jax.jit(
-            lambda p, x, control: reference.forward(
-                p, x, cell=cell, vocab=vocab, control=control),
-            static_argnums=2)
-    return _FWD[cell, vocab]
-
-
-def logit_gaps(conf: dict, seed: int, seqs: list, max_len: int,
+def logit_gaps(model, conf: dict, seed: int, seqs: list, max_len: int,
                control: bool = False) -> dict:
     """For each served token, the gap between the reference's best logit
     at its position and the reference's logit of that token.  Returns,
@@ -372,8 +359,7 @@ def logit_gaps(conf: dict, seed: int, seqs: list, max_len: int,
     Every sequence is padded to ``max_len`` so that one program serves
     them all."""
     import jax.numpy as jnp
-    params = weights.make(conf, seed)
-    fwd = _forward(conf["minrnn"]["cell"], conf["vocab_size"])
+    params = weights.make(model, conf, seed)
     out = {"program_max": 0.0, "tokens": 0}
     if control:
         out["control_max"] = 0.0
@@ -383,12 +369,12 @@ def logit_gaps(conf: dict, seed: int, seqs: list, max_len: int,
         x[0, :len(toks)] = toks
         pos = np.arange(len(prompt) - 1, len(toks))
         rows = np.arange(len(served))
-        ref = np.asarray(fwd(params, jnp.asarray(x), False))[0, pos]
+        ref = np.asarray(model.forward(params, jnp.asarray(x), conf))[0, pos]
         best = ref.max(-1)
         picks = {"program": np.asarray(served)}
         if control:
-            picks["control"] = np.asarray(
-                fwd(params, jnp.asarray(x), True))[0, pos].argmax(-1)
+            picks["control"] = np.asarray(model.forward(
+                params, jnp.asarray(x), conf, control=True))[0, pos].argmax(-1)
         for who, tok in picks.items():
             gap = best - ref[rows, tok]
             out[who + "_max"] = max(out[who + "_max"], float(gap.max()))

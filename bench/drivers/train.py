@@ -15,12 +15,13 @@ every step in flight then has returned its loss, and the rate is the
 tokens of every step over that whole time.
 
 The check follows the first ``CHECKED_STEPS`` steps in the float32
-reference, with the same batches, and compares each step's loss, the
-norm of each leaf of the first gradient as the optimizer received it
-(its first moment after one step, over 1 - b1), the norm of each leaf's
-change over the checked steps, and the norm of each leaf's difference
-from the reference's first gradient.  A number the cell's file gives no
-limit is not compared.
+reference (the ``loss_and_grad`` of the configuration's model module,
+``bench/models/``, then ``reference.adamw``), with the same batches, and
+compares each step's loss, the norm of each leaf of the first gradient
+as the optimizer received it (its first moment after one step, over
+1 - b1), the norm of each leaf's change over the checked steps, and the
+norm of each leaf's difference from the reference's first gradient.  A
+number the cell's file gives no limit is not compared.
 """
 
 from __future__ import annotations
@@ -122,9 +123,9 @@ class Session:
         from repro.training import train_step
 
         conf = self.cell.conf
-        cfg = harness.program_config(conf)
+        cfg = harness.program_config(conf, self.cell.model)
         ocfg = opt_lib.AdamWConfig(**self.opt)
-        self.params0 = weights.make(conf, self.run.seed)
+        self.params0 = weights.make(self.cell.model, conf, self.run.seed)
         self.params = self.params0
         self.opt_state = jax.jit(functools.partial(opt_lib.init, ocfg))(
             self.params)
@@ -199,13 +200,13 @@ class Session:
     def check(self) -> dict:
         self.params = self.opt_state = self.step_fn = None
         gc.collect()
-        ref = follow_reference(self.cell.conf, self.run.seed, self.batch,
-                               self.seq_len, self.opt,
-                               self.cell.settings["reference_rows"])
+        ref = follow_reference(self.cell.model, self.cell.conf,
+                               self.run.seed, self.batch, self.seq_len,
+                               self.opt, self.cell.settings["reference_rows"])
         return compare(self.readings, ref, self.cell.settings["limits"])
 
 
-def follow_reference(conf, seed, batch, seq_len, opt, rows,
+def follow_reference(model, conf, seed, batch, seq_len, opt, rows,
                      control: bool = False) -> dict:
     """The reference's readings over the checked steps, with the same
     weights and batches as the program."""
@@ -215,7 +216,7 @@ def follow_reference(conf, seed, batch, seq_len, opt, rows,
             "grad_clip": 1.0, "warmup_steps": 100, "total_steps": 10000,
             "min_lr_ratio": 0.1}
     full.update(opt)
-    stored = weights.make(conf, seed)
+    stored = weights.make(model, conf, seed)
     p0 = jax.tree.map(lambda a: a.astype(jnp.float32), stored)
     store_dtype = jax.tree.leaves(stored)[0].dtype
     del stored
@@ -226,9 +227,8 @@ def follow_reference(conf, seed, batch, seq_len, opt, rows,
     for step in range(1, CHECKED_STEPS + 1):
         b = {k: jnp.asarray(v) for k, v in
              corpus.train_batch(seed, step, batch, seq_len).items()}
-        loss, grads = reference.loss_and_grad(
-            params, b, cell=conf["minrnn"]["cell"], vocab=conf["vocab_size"],
-            rows=rows, control=control)
+        loss, grads = model.loss_and_grad(params, b, conf, rows,
+                                          control=control)
         out["loss"].append(float(loss))
         params, mu, nu, clipped = reference.adamw(
             full, params, grads, mu, nu, step, store_dtype)

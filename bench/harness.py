@@ -1,19 +1,29 @@
-"""The benchmark's harness: finds a cell's files by name, keeps the run's
-clock, spans and profiler, and turns a driver's window into the result
-line.  ``run.py`` is the command; tests call ``execute`` directly, past
-the look for a chip, to drive a whole run on the CPU at a small size.
+"""The benchmark's harness: finds a cell's files and its configuration's
+model module by name, keeps the run's clock, spans and profiler, and turns
+a driver's window into the result line.  ``run.py`` is the command; tests
+call ``execute`` directly, past the look for a chip, to drive a whole run
+on the CPU at a small size.
 """
 
+import dataclasses
 import importlib
 import json
 import os
 import sys
 import time
 
+import models
+
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
 OUT_DIR = os.path.join(ROOT, ".bench_out")
 TRACE_SECONDS = 6.0      # the traced part of a --trace 1 window, at most
+# keys of a configuration file that describe it, not the program's config:
+# ``model`` names its module under ``bench/models/``; ``published`` gives
+# the source's value of each key in ``reduced``; ``deployment`` says how
+# many chips share a layer, and how
+META = {"arch", "model", "source", "reduced", "published", "assumed",
+        "deployment", "why"}
 
 
 def load_json(*parts):
@@ -27,7 +37,7 @@ def log(msg):
 
 class Cell:
     """Everything ``BENCHMARK.json`` and the cell's files say about one
-    workload, looked up by its name."""
+    workload, and its configuration's model module, looked up by name."""
 
     def __init__(self, name: str, root: str = ROOT):
         bench = load_json(root, "BENCHMARK.json")
@@ -40,6 +50,7 @@ class Cell:
         conf_entry = [c for c in bench["configs"]
                       if c["name"] == self.entry["config"]][0]
         self.conf = load_json(root, conf_entry["file"])
+        self.model = models.load(self.conf)
         self.traffic = load_json(root, "bench", "traffic",
                                  self.entry["traffic"] + ".json")
         self.settings = load_json(root, "bench", "cells", name + ".json")
@@ -139,24 +150,46 @@ def per_layer_metrics(cell: Cell, ctx: dict) -> dict:
     return out
 
 
-def program_config(conf: dict):
+def stated(cfg, conf: dict, model) -> dict:
+    """The program's value of every key of the configuration file ``conf``
+    outside ``META``, as the file writes it: a field of the program's
+    config ``cfg`` (a nested group as a dict of the keys the file gives
+    it), or a key of the model module's ``source_values(cfg)``, the
+    source's own name for a value of the program, as a catalog model's
+    file keeps them.  Any other key stops the run, so every key the file
+    states is checked against the program."""
+    source = (model.source_values(cfg) if hasattr(model, "source_values")
+              else {})
+    out = {}
+    for key, want in conf.items():
+        if key in META:
+            continue
+        if hasattr(cfg, key):
+            have = getattr(cfg, key)
+            if dataclasses.is_dataclass(have):
+                have = dataclasses.asdict(have)
+                have = {k: have[k] for k in want}
+        elif key in source:
+            have = source[key]
+        else:
+            raise SystemExit(f"{conf['arch']}: the configuration file states "
+                             f"{key!r}, which is neither a field of the "
+                             f"program's config nor a source value of "
+                             f"bench/models/{conf['model']}.py")
+        out[key] = have
+    return out
+
+
+def program_config(conf: dict, model):
     """The program's registered config for ``conf['arch']``, checked
     against every size and setting the configuration file states."""
-    import dataclasses
-
     from repro.configs import archs
     cfg = archs.get(conf["arch"])
-    meta = {"arch", "source", "reduced", "assumed", "why"}
-    for key, want in conf.items():
-        if key in meta:
-            continue
-        have = getattr(cfg, key)
-        if dataclasses.is_dataclass(have):
-            have = dataclasses.asdict(have)
-            have = {k: have[k] for k in want}
-        if have != want:
+    for key, have in stated(cfg, conf, model).items():
+        if have != conf[key]:
             raise SystemExit(f"{conf['arch']}: {key} is {have!r} in the "
-                             f"program, {want!r} in the configuration file")
+                             f"program, {conf[key]!r} in the configuration "
+                             f"file")
     return cfg
 
 
@@ -186,7 +219,7 @@ def execute(cell: Cell, args, devices, t_process: float) -> int:
         from trace_reduce import reduce_trace
         red = reduce_trace(os.path.join(OUT_DIR, "trace"), cell.chips)
         ctx = dict(window["layer_ctx"], trace=red,
-                   shape=work.Shape.from_config(cell.conf),
+                   shape=cell.model.counts(cell.conf),
                    peak=work.peaks(device["kind"]), chips=cell.chips,
                    spans=run.spans, traced=run.traced)
         metrics = per_layer_metrics(cell, ctx)

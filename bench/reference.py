@@ -1,37 +1,18 @@
-"""Plain float32 reference of the paper's minGRU and minLSTM language models.
+"""Pieces every model module's plain float32 reference shares.
 
-Written from Feng et al. 2024, "Were RNNs All We Needed?" (arXiv:2410.01201,
-sections 3.1, 3.2 and App. C) and the residual block layout of
-``core/blocks.py``; it imports nothing of the program.  Every matrix
-product runs at ``precision="highest"`` (on a TPU a float32 product is
-otherwise rounded to bfloat16).
+Each model family's reference lives in its module under ``bench/models/``
+and builds its blocks from these.  They import nothing of the program.
+Every matrix product runs at ``precision="highest"`` (on a TPU a float32
+product is otherwise rounded to bfloat16).
 
-Per layer, for input x (B, T, d):
+``_mm(x, w, control)`` is the product a block uses: at the highest
+precision, or with ``control=True`` in float8, e4m3 operands with a scale
+per row of activations and per output column of weights, and in the
+backward an e5m2 gradient with one scale.  That is the reference at the
+next precision below a bfloat16 configuration, used to show that the
+benchmark's comparison fails a lower-precision program.
 
-    y  = RMSNorm(x) * scale                          eps 1e-6
-    y  = causal depthwise conv, 4 taps, plus bias    zero left padding
-    minGRU:  z = sigmoid(y Wz + bz),  h~ = g(y Wh + bh)
-             h_t = (1 - z_t) h_{t-1} + z_t h~_t
-    minLSTM: f = sigmoid(y Wf + bf), i = sigmoid(y Wi + bi), h~ = g(y Wh + bh)
-             h_t = f/(f+i) h_{t-1} + i/(f+i) h~_t
-    x  = x + h Wdown
-    x  = x + gelu(RMSNorm(x) Win + bin) Wout + bout  (tanh-form gelu)
-
-with h_{-1} = 0 and g(v) = v + 1/2 for v >= 0, sigmoid(v) otherwise.  The
-logits are RMSNorm(x) times the tied embedding table.
-
-Departures, each equal in exact arithmetic:
-  * the paper's App. B scans in log space; this one scans (a, b) linearly
-    with ``lax.associative_scan`` in float32, where the gates in (0, 1)
-    keep it stable;
-  * minLSTM's f/(f+i) is taken as written, not through Algorithm 8's
-    softplus form.
-
-``control=True`` computes every matrix product in float8: e4m3 operands
-with a scale per row of activations and per output column of weights,
-and in the backward an e5m2 gradient with one scale.  That is the
-reference at the next precision below the configuration's bfloat16, used
-to show that the benchmark's comparison fails a lower-precision program.
+AdamW follows ``training.optimizer.AdamWConfig`` for the training cells.
 """
 
 from __future__ import annotations
@@ -42,6 +23,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+
 
 def _f8(a, axis=None, dtype=jnp.float8_e4m3fn):
     """Round ``a`` to float8 with an absmax scale along ``axis`` (the
@@ -86,99 +68,6 @@ def _mm(x, w, control):
 
 def _rmsnorm(x, scale):
     return x * lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * scale
-
-
-def _g(v):
-    return jnp.where(v >= 0, v + 0.5, jax.nn.sigmoid(v))
-
-
-def _gelu(x):
-    return 0.5 * x * (1.0 + jnp.tanh(math.sqrt(2.0 / math.pi)
-                                     * (x + 0.044715 * x ** 3)))
-
-
-def _linear_scan(a, b):
-    """h_t = a_t h_{t-1} + b_t along axis 1, h_{-1} = 0."""
-    def combine(l, r):
-        return l[0] * r[0], r[0] * l[1] + r[1]
-    return lax.associative_scan(combine, (a, b), axis=1)[1]
-
-
-def _block(p, x, cell, control):
-    y = _rmsnorm(x, p["norm_rnn"]["scale"])
-    k = p["conv"]["kernel"]                          # (taps, d)
-    taps = k.shape[0]
-    yp = jnp.pad(y, ((0, 0), (taps - 1, 0), (0, 0)))
-    t = y.shape[1]
-    y = sum(yp[:, i:i + t] * k[i] for i in range(taps)) + p["conv"]["bias"]
-    r = p["rnn"]
-
-    def proj(name):
-        return _mm(y, r[name]["kernel"], control) + r[name]["bias"]
-
-    h_tilde = _g(proj("wh"))
-    if cell == "mingru":
-        z = jax.nn.sigmoid(proj("wz"))
-        a, b = 1.0 - z, z * h_tilde
-    else:
-        f = jax.nn.sigmoid(proj("wf"))
-        i = jax.nn.sigmoid(proj("wi"))
-        a, b = f / (f + i), i / (f + i) * h_tilde
-    h = _linear_scan(a, b)
-    x = x + _mm(h, p["down"]["kernel"], control)
-    y = _rmsnorm(x, p["norm_mlp"]["scale"])
-    y = _gelu(_mm(y, p["mlp_in"]["kernel"], control) + p["mlp_in"]["bias"])
-    return x + _mm(y, p["mlp_out"]["kernel"], control) + p["mlp_out"]["bias"]
-
-
-def forward(params, tokens, *, cell: str, vocab: int, control: bool = False):
-    """tokens (B, T) int -> logits (B, T, vocab) float32."""
-    params = jax.tree.map(lambda a: a.astype(jnp.float32), params)
-    table = params["embed"]["table"]
-    x = table[tokens]
-
-    def body(x, p):
-        return jax.checkpoint(functools.partial(
-            _block, cell=cell, control=control))(p, x), None
-
-    x, _ = lax.scan(body, x, params["layers"]["blocks"])
-    x = _rmsnorm(x, params["final_norm"]["scale"])
-    return _mm(x, table.T, control)[..., :vocab]
-
-
-def nll_sum(params, batch, *, cell: str, vocab: int, control: bool = False):
-    """Summed next-token negative log-likelihood over labels >= 0."""
-    logits = forward(params, batch["tokens"], cell=cell, vocab=vocab,
-                     control=control)
-    labels = batch["labels"]
-    mask = labels >= 0
-    logz = jax.nn.logsumexp(logits, -1)
-    gold = jnp.take_along_axis(logits, jnp.maximum(labels, 0)[..., None],
-                               -1)[..., 0]
-    return jnp.sum(jnp.where(mask, logz - gold, 0.0))
-
-
-@functools.partial(jax.jit, static_argnames=("cell", "vocab", "control"))
-def nll_sum_and_grad(params, batch, *, cell, vocab, control=False):
-    """(summed NLL, its float32 gradient) for one block of rows."""
-    return jax.value_and_grad(nll_sum)(params, batch, cell=cell, vocab=vocab,
-                                       control=control)
-
-
-def loss_and_grad(params, batch, *, cell: str, vocab: int, rows: int,
-                  control: bool = False):
-    """Mean NLL over the batch and its gradient, ``rows`` rows at a time
-    so that the float32 activations fit beside the program's memory."""
-    n = batch["tokens"].shape[0]
-    total, grads = 0.0, None
-    for r in range(0, n, rows):
-        part = {k: v[r:r + rows] for k, v in batch.items()}
-        s, g = nll_sum_and_grad(params, part, cell=cell, vocab=vocab,
-                                control=control)
-        total = total + s
-        grads = g if grads is None else jax.tree.map(jnp.add, grads, g)
-    count = jnp.sum(batch["labels"] >= 0).astype(jnp.float32)
-    return total / count, jax.tree.map(lambda g: g / count, grads)
 
 
 # ---------------------------------------------------------------------------

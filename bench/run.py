@@ -5,9 +5,11 @@
         --trace 0
 
 Everything a cell needs is found by name: the cell's entry in
-``BENCHMARK.json`` names its configuration (``bench/configs/<config>.json``)
-and its traffic mix (``bench/traffic/<traffic>.json``, whose ``driver`` key
-picks the general generator ``bench/drivers/<driver>.py``); the cell's own
+``BENCHMARK.json`` names its configuration (``bench/configs/<config>.json``,
+whose ``model`` key names the model module ``bench/models/<model>.py``:
+the family's weight layout, plain reference and work counts) and its
+traffic mix (``bench/traffic/<traffic>.json``, whose ``driver`` key picks
+the general generator ``bench/drivers/<driver>.py``); the cell's own
 engine settings and limits are in ``bench/cells/<cell>.json``, and each
 per-layer metric is read by ``bench/metrics/<family>.py``, where the family
 is the metric's name up to its first dot.
@@ -16,9 +18,9 @@ With ``--trace 0`` the result carries the cell's end-to-end metrics; with
 ``--trace 1`` the last seconds of the window run under the profiler and
 the result carries the per-layer metrics, the device's busy time and a
 breakdown.  Either way the window's output is compared with the plain
-reference (``reference.py``) once the window has closed, and the numbers
-compared are printed beside their limits, last on standard error and
-last in the result line.
+reference of the configuration's model module once the window has closed,
+and the numbers compared are printed beside their limits, last on
+standard error and last in the result line.
 
 The last line of standard output is one JSON object.  Without a TPU, or
 with fewer chips than the cell asks for, the run prints no result and

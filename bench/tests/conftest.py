@@ -4,7 +4,8 @@
     JAX_PLATFORMS=cpu python -m pytest -q bench/tests
 
 ``rehearsal_root`` copies the benchmark into a temporary directory and
-cuts every cell to a size the CPU's Pallas interpreter runs in seconds;
+cuts every cell to a size the CPU's Pallas interpreter runs in seconds:
+each configuration to its program's own smoke preset (``smoke_conf``);
 ``rehearse`` then drives a whole run past the look for a chip, through
 ``harness.execute``, and returns its result line.
 """
@@ -27,8 +28,15 @@ REPO = os.path.dirname(BENCH)
 sys.path.insert(0, os.path.join(REPO, "src"))
 sys.path.insert(0, BENCH)
 
-SMOKE_SIZES = dict(n_layers=3, d_model=64, d_ff=256, param_dtype="float32",
-                   compute_dtype="float32", remat="none")
+
+def smoke_conf(conf: dict) -> dict:
+    """``conf`` with every key it states set to the value of the program's
+    smoke preset for its arch, ``archs.smoke(conf["arch"])``."""
+    import harness
+    import models
+    from repro.configs import archs
+    return dict(conf, **harness.stated(archs.smoke(conf["arch"]), conf,
+                                       models.load(conf)))
 
 
 def _edit(path, fn):
@@ -48,7 +56,7 @@ def rehearsal_root(tmp_path, monkeypatch):
     b = os.path.join(root, "bench")
     for name in os.listdir(os.path.join(b, "configs")):
         _edit(os.path.join(b, "configs", name),
-              lambda c: c.update(SMOKE_SIZES))
+              lambda c: c.update(smoke_conf(c)))
 
     def small_traffic(t):
         if "prompt_bytes" in t:
@@ -74,7 +82,7 @@ def rehearsal_root(tmp_path, monkeypatch):
     import harness
     from repro.configs import archs
     monkeypatch.setattr(harness, "program_config",
-                        lambda conf: archs.smoke(conf["arch"]))
+                        lambda conf, model: archs.smoke(conf["arch"]))
     return root
 
 

@@ -55,7 +55,8 @@ def test_control_fails_serving_limit_at_cell_width(workload):
     rng = np.random.default_rng(7)
     seqs = [(corpus.prompt(rng, 48), corpus.prompt(rng, 208), 0)
             for _ in range(4)]
-    gaps = serve.logit_gaps(cell.conf, 2**32 + 17, seqs, 256, control=True)
+    gaps = serve.logit_gaps(cell.model, cell.conf, 2**32 + 17, seqs, 256,
+                            control=True)
     assert gaps["control_max"] > cell.settings["limits"]["max_logit_gap"], \
         gaps
 
@@ -72,7 +73,8 @@ def test_control_fails_training_limits_at_cell_width(workload):
     import harness
     from drivers import train
     cell = harness.Cell(workload)
-    args = (cell.conf, 2**32 + 19, 2, 128, cell.traffic["optimizer"], 2)
+    args = (cell.model, cell.conf, 2**32 + 19, 2, 128,
+            cell.traffic["optimizer"], 2)
     ref = train.follow_reference(*args)
     ctrl = train.follow_reference(*args, control=True)
     got = train.compare(ctrl, ref, cell.settings["limits"])

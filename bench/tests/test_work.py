@@ -1,4 +1,5 @@
-"""The yardstick's operation and byte counts against counts by hand."""
+"""The yardstick's operation and byte counts against counts by hand: the
+minRNN model module's ``counts`` and ``layout``, and the peaks."""
 
 from __future__ import annotations
 
@@ -8,9 +9,9 @@ import os
 
 import pytest
 
-import weights
 import work
 from conftest import BENCH
+from models import minrnn
 
 # (config, matrix-product weights per block, all weights per block)
 # mingru: 2*768*1536 + 1536*768 + 2*768*3072 = 8,257,536 in products;
@@ -27,7 +28,7 @@ def conf(name):
 
 @pytest.mark.parametrize("name", sorted(HAND))
 def test_counts_by_hand(name):
-    s = work.Shape.from_config(conf(name))
+    s = minrnn.counts(conf(name))
     mm, allp = HAND[name]
     assert s.block_matmul_params == mm
     assert s.block_params == allp
@@ -43,10 +44,10 @@ def test_counts_by_hand(name):
 @pytest.mark.parametrize("name", sorted(HAND))
 def test_block_params_match_the_weights_made(name):
     c = conf(name)
-    lay = weights.layout(c)
+    lay = minrnn.layout(c)
     per_block = sum(math.prod(shape[1:]) for path, (shape, _) in lay.items()
                     if path[:2] == ("layers", "blocks"))
-    assert per_block == work.Shape.from_config(c).block_params
+    assert per_block == minrnn.counts(c).block_params
 
 
 def test_peaks_and_unknown_device():
